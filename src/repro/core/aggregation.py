@@ -46,6 +46,7 @@ from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import AggConfig
 from repro.core.fedavg import fedavg_stacked
@@ -494,7 +495,7 @@ def _fedbuff_factory():
 # very few active clients every score would be inf and argmin over
 # all-inf is a degenerate tie; a large-but-finite sentinel keeps the
 # ordering (active < inactive) strict and the arithmetic NaN-free.
-_BIG = jnp.float32(1e30)
+_BIG = np.float32(1e30)  # numpy: a jnp scalar would start the backend at import
 
 
 def _pairwise_sq_dists(vecs: jnp.ndarray, use_pallas: bool) -> jnp.ndarray:

@@ -42,6 +42,7 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import AvailabilityConfig
 
@@ -52,7 +53,7 @@ PyTree = Any
 # split index the round consumes).
 _FAULT_TAG = 0xFA117
 # empty slot sentinel for the pending-arrival round
-NO_PENDING = jnp.int32(-1)
+NO_PENDING = np.int32(-1)  # numpy: a jnp scalar would start the backend
 # denominator floor for survivor-mass renormalization (never divides by
 # zero; zero-survivor rounds are where-gated to a no-op anyway)
 _MASS_FLOOR = 1e-12

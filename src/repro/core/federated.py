@@ -719,7 +719,6 @@ def make_sharded_round(gpo_cfg: GPOConfig, fed_cfg: FedConfig,
     the stacked engine given the same keys.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     gpo_cfg = fed_cfg.resolve_gpo(gpo_cfg)  # runtime attention override
     fed_cfg.privacy.validate()
@@ -935,8 +934,8 @@ def make_sharded_round(gpo_cfg: GPOConfig, fed_cfg: FedConfig,
         out = inner(*base, resid=resid, byz_key=bk)
         return out if ef else out[:n_out]
 
-    sharded = shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                        out_specs=tuple(out_specs), check_rep=False)
+    sharded = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                            out_specs=tuple(out_specs), check_vma=False)
 
     def round_fn(client_params, opt_states, keys, group_ids, weights,
                  server_state, *rest):

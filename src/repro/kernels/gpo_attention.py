@@ -154,26 +154,31 @@ def _gpo_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
         s = jnp.where(_np_tile_mask(q_start, k_start, num_ctx, bq, bk), s,
                       NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]  # (bq, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = (acc_ref[...] * alpha[:, None]
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = (acc_ref[...] * alpha
                         + jax.lax.dot(p.astype(v.dtype), v))
         m_ref[...] = m_new
 
     @pl.when(t == last)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
         lse_ref[0] = m_ref[...] + jnp.log(l)
 
 
 def _gpo_forward(q, k, v, *, num_ctx: int, bq: int, bk: int, interpret: bool,
                  banded: bool):
-    """(o (h, s, hd), lse (h, s) f32). ``banded`` must be pre-resolved
-    (bq == bk and the band does not saturate the grid)."""
+    """(o (h, s, hd), lse (h, s, 1) f32). ``banded`` must be pre-resolved
+    (bq == bk and the band does not saturate the grid).
+
+    Per-row statistics (``lse``, the m/l scratch) carry a trailing unit
+    axis: a (bq, 1) block equals the array's last dim and keeps bq on
+    the sublane axis, which is what the TPU lowering's (8, 128) block
+    rule accepts; a (1, bq) row block over (h, s) is refused."""
     h, s, hd = q.shape
     num_qb, num_kb = s // bq, s // bk
     scale = 1.0 / (hd ** 0.5)
@@ -182,9 +187,6 @@ def _gpo_forward(q, k, v, *, num_ctx: int, bq: int, bk: int, interpret: bool,
 
     def idx(i, j, t):
         return (i, j, 0)
-
-    def row_idx(i, j, t):
-        return (i, j)
 
     kernel = functools.partial(_gpo_fwd_kernel, scale=scale, num_ctx=num_ctx,
                                ctx_blocks=ctx_blocks, num_kb=num_kb, bq=bq,
@@ -199,15 +201,15 @@ def _gpo_forward(q, k, v, *, num_ctx: int, bq: int, bk: int, interpret: bool,
         ],
         out_specs=[
             pl.BlockSpec((1, bq, hd), idx),
-            pl.BlockSpec((1, bq), row_idx),
+            pl.BlockSpec((1, bq, 1), idx),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((h, s, hd), q.dtype),
-            jax.ShapeDtypeStruct((h, s), jnp.float32),
+            jax.ShapeDtypeStruct((h, s, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
         interpret=interpret,
@@ -248,9 +250,9 @@ def _gpo_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
         s = jnp.where(_np_tile_mask(q_start, k_start, num_ctx, bq, bk), s,
                       NEG_INF)
-        p = jnp.exp(s - lse_ref[0][:, None])  # masked entries -> exactly 0
+        p = jnp.exp(s - lse_ref[0])  # masked entries -> exactly 0
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))  # (bq, bk)
-        ds = p * (dp - delta_ref[0][:, None]) * scale
+        ds = p * (dp - delta_ref[0]) * scale
         acc_ref[...] = acc_ref[...] + jax.lax.dot(ds, k)
 
     @pl.when(t == last)
@@ -302,12 +304,12 @@ def _gpo_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
         s = jnp.where(_np_tile_mask(q_start, k_start, num_ctx, bq, bk), s,
                       NEG_INF)
-        p = jnp.exp(s - lse_ref[0][:, None])  # (bq, bk)
+        p = jnp.exp(s - lse_ref[0])  # (bq, bk)
         # dv += p^T do ; ds = p * (dp - delta) ; dk += ds^T q
         dv_acc[...] = dv_acc[...] + jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())))
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
-        ds = p * (dp - delta_ref[0][:, None]) * scale
+        ds = p * (dp - delta_ref[0]) * scale
         dk_acc[...] = dk_acc[...] + jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())))
 
@@ -326,7 +328,8 @@ def _gpo_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _gpo_backward(q, k, v, do, lse, delta, *, num_ctx: int, bq: int, bk: int,
                   interpret: bool, banded: bool):
-    """(dq, dk, dv) via the two banded backward kernels."""
+    """(dq, dk, dv) via the two banded backward kernels; ``lse`` and
+    ``delta`` are (h, s, 1) row statistics (see ``_gpo_forward``)."""
     h, s, hd = q.shape
     num_qb, num_kb = s // bq, s // bk
     scale = 1.0 / (hd ** 0.5)
@@ -337,9 +340,6 @@ def _gpo_backward(q, k, v, do, lse, delta, *, num_ctx: int, bq: int, bk: int,
 
     def idx(i, j, t):
         return (i, j, 0)
-
-    def row_idx(i, j, t):
-        return (i, j)
 
     dq_kernel = functools.partial(
         _gpo_bwd_dq_kernel, scale=scale, num_ctx=num_ctx,
@@ -352,8 +352,8 @@ def _gpo_backward(q, k, v, do, lse, delta, *, num_ctx: int, bq: int, bk: int,
             pl.BlockSpec((1, bk, hd), kv_idx),
             pl.BlockSpec((1, bk, hd), kv_idx),
             pl.BlockSpec((1, bq, hd), idx),
-            pl.BlockSpec((1, bq), row_idx),
-            pl.BlockSpec((1, bq), row_idx),
+            pl.BlockSpec((1, bq, 1), idx),
+            pl.BlockSpec((1, bq, 1), idx),
         ],
         out_specs=pl.BlockSpec((1, bq, hd), idx),
         out_shape=jax.ShapeDtypeStruct((h, s, hd), q.dtype),
@@ -386,9 +386,6 @@ def _gpo_backward(q, k, v, do, lse, delta, *, num_ctx: int, bq: int, bk: int,
     def t_kv_idx(i, t):
         return (i, decode(t)[0], 0)
 
-    def t_row_idx(i, t):
-        return (i, decode(t)[1])
-
     dkdv_kernel = functools.partial(
         _gpo_bwd_dkdv_kernel, scale=scale, num_ctx=num_ctx,
         ctx_blocks=ctx_blocks, num_qb=num_qb, bq=bq, bk=bk)
@@ -400,8 +397,8 @@ def _gpo_backward(q, k, v, do, lse, delta, *, num_ctx: int, bq: int, bk: int,
             pl.BlockSpec((1, bk, hd), t_kv_idx),
             pl.BlockSpec((1, bk, hd), t_kv_idx),
             pl.BlockSpec((1, bq, hd), t_q_idx),
-            pl.BlockSpec((1, bq), t_row_idx),
-            pl.BlockSpec((1, bq), t_row_idx),
+            pl.BlockSpec((1, bq, 1), t_q_idx),
+            pl.BlockSpec((1, bq, 1), t_q_idx),
         ],
         out_specs=[
             pl.BlockSpec((1, bk, hd), t_kv_idx),
@@ -443,7 +440,8 @@ def _gpo_attention_bwd(num_ctx, bq, bk, interpret, banded, res, do):
     q, k, v, o, lse = res
     # preprocessing pass: delta_i = sum_d do_id * o_id = sum_j p_ij dp_ij,
     # the softmax-jacobian row term shared by every tile of row i
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
+                    keepdims=True)
     return _gpo_backward(q, k, v, do.astype(q.dtype), lse, delta,
                          num_ctx=num_ctx, bq=bq, bk=bk, interpret=interpret,
                          banded=banded)

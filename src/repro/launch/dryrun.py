@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture x input shape) on
 the production meshes WITHOUT allocating anything (params/batches/caches are
 ShapeDtypeStructs).
@@ -16,9 +13,14 @@ Per pair this prints/records:
 
 The 2x16x16 multi-pod pass proves the 'pod' axis shards (hierarchical
 FedAvg / data parallelism over DCI); the roofline table is single-pod.
-"""  # noqa: E402
+
+``main`` forces 512 host-platform devices before JAX starts its backend;
+importing this module (tests and benchmarks call ``lower_gpo_round``)
+changes nothing in the importing process.
+"""
 
 import argparse
+import os
 import json
 import time
 import traceback
@@ -30,7 +32,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import INPUT_SHAPES, get_arch, override
 from repro.core.trainer import make_prefill_step, make_serve_step, make_train_step
 from repro.launch import roofline as rl
-from repro.launch.mesh import data_axes, make_production_mesh
+from repro.launch.mesh import data_axes, make_mesh, make_production_mesh
 from repro.launch.sharding import (
     adafactor_state_shardings,
     adam_state_shardings,
@@ -231,10 +233,10 @@ def lower_gpo_round(agg_name: str, *, clients: int = 8,
 
     if edges > 1:
         # §14 two-level edge mesh: one client per device, E edge shards
-        mesh = jax.make_mesh((edges, clients // edges), ("edge", "data"))
+        mesh = make_mesh((edges, clients // edges), ("edge", "data"))
         caxes = ("edge", "data")
     else:
-        mesh = jax.make_mesh((clients,), ("data",))
+        mesh = make_mesh((clients,), ("data",))
         caxes = ("data",)
     data = make_survey_data(SurveyConfig(num_groups=clients,
                                          num_questions=30, d_embed=16,
@@ -356,6 +358,7 @@ def lower_gpo_round(agg_name: str, *, clients: int = 8,
 
 
 def main() -> None:
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None, choices=sorted(INPUT_SHAPES))

@@ -2,10 +2,22 @@
 
 Functions, not module-level constants: importing this module never touches
 jax device state (the dry-run must set XLA_FLAGS before first jax init).
+
+Every mesh here has ``Auto`` axes: ``jax.make_mesh`` defaults to
+``Explicit`` axes, which ``with_sharding_constraint`` (and so
+``models.partitioning.shard_act``) refuses.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with ``Auto`` axis types (module docstring)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -14,12 +26,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     (DCI-connected) carrying hierarchical FedAvg / data parallelism."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over however many (host) devices exist — tests/examples."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def make_edge_mesh(num_edges: int, clients_per_edge: int):
@@ -32,7 +44,7 @@ def make_edge_mesh(num_edges: int, clients_per_edge: int):
     all-gather of only E candidate rows (int8 when the §10 codec is on).
     The linear family keeps its single psum over both axes — which IS
     the composed two-hop partial-sum schedule on a real torus."""
-    return jax.make_mesh((num_edges, clients_per_edge), ("edge", "data"))
+    return make_mesh((num_edges, clients_per_edge), ("edge", "data"))
 
 
 def data_axes(mesh) -> tuple[str, ...]:

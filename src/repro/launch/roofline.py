@@ -38,12 +38,17 @@ _COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                 "collective-permute")
 
 # e.g.  %all-reduce.5 = bf16[16,512]{1,0} all-reduce(
+# The kind must be the instruction's opcode (right after the result
+# shape), not an operand name: ``get-tuple-element(%all-reduce.1)`` reads
+# a tuple collective's result and moves no bytes.
 _OP_RE = re.compile(
-    r"=\s*(?:\()?\s*([a-z0-9]+)\[([\d,]*)\][^=]*?\b(" + "|".join(
-        _COLLECTIVES) + r")\b")
+    r"=\s*([a-z0-9]+)\[([\d,]*)\]\S*\s+(" + "|".join(_COLLECTIVES)
+    + r")(?:-done)?\(")
 # tuple-result collectives:  = (bf16[8,128], bf16[8,128]) all-reduce(
+# (TPU layouts such as {1,0:T(8,128)} put parentheses inside the tuple).
+# An async pair counts once, at its ``-done``; ``-start`` never matches.
 _TUPLE_RE = re.compile(
-    r"=\s*\(([^)]*)\)\s*(" + "|".join(_COLLECTIVES) + r")\b")
+    r"=\s*\((.*?)\)\s*(" + "|".join(_COLLECTIVES) + r")(?:-done)?\(")
 _SHAPE_RE = re.compile(r"([a-z0-9]+)\[([\d,]*)\]")
 
 
@@ -78,8 +83,6 @@ def parse_collectives(hlo_text: str) -> CollectiveStats:
         stats.count_by_kind[kind] = stats.count_by_kind.get(kind, 0) + 1
 
     for line in hlo_text.splitlines():
-        if "-start" in line:  # avoid double counting start/done pairs
-            continue
         m = _TUPLE_RE.search(line)  # tuple results first (multi-operand)
         if m:
             shapes, kind = m.groups()

@@ -9,6 +9,10 @@ model is the product, not the training loop. Requests flow through
 queue, bucketed continuous batching, LRU prefix/KV cache over shared ICL
 contexts, and optional int8 weights (``--int8``).
 
+The first line printed names the device JAX chose. To require the chip,
+set ``JAX_PLATFORMS=tpu``: JAX then fails at start-up instead of falling
+back to the CPU, where the Pallas kernels would run in interpret mode.
+
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --smoke \
       --prompt-len 16 --gen-len 16 --batch 4
   PYTHONPATH=src python -m repro.launch.serve --gpo --requests 64
@@ -48,6 +52,7 @@ from repro.core import (
 )
 from repro.data import SurveyConfig, make_survey_data, split_groups
 from repro.models import init_params
+from repro.utils.runtime import device_info, enable_compile_cache
 
 
 def serve_lm(args) -> None:
@@ -195,6 +200,8 @@ def main() -> None:
                          "time and serve through the fused int8 kernel "
                          "(DESIGN.md §12)")
     args = ap.parse_args()
+    print("device:", device_info())
+    enable_compile_cache()
     if args.gpo:
         serve_gpo(args)
     else:

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Run the full (architecture x input-shape) dry-run sweep, resumably.
 
   PYTHONPATH=src python -m repro.launch.sweep --out results/dryrun.jsonl
@@ -8,12 +5,14 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 Each pair is lowered+compiled in-process; results append as JSON lines.
 Already-recorded (arch, shape, multi_pod) triples are skipped, so the sweep
-can be re-launched after interruption.
-"""  # noqa: E402
+can be re-launched after interruption. ``main`` forces 512 host-platform
+devices before JAX starts its backend; importing the module does not.
+"""
 
 import argparse
 import gc
 import json
+import os
 import time
 import traceback
 
@@ -34,6 +33,7 @@ def done_keys(path: str) -> set:
 
 
 def main() -> None:
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", required=True)
     ap.add_argument("--multi-pod", action="store_true")

@@ -22,6 +22,10 @@ from the Rényi accountant), and ``--compress`` / ``--topk-frac`` /
 stochastic quantization or top-k sparsification with an EF21 residual,
 DESIGN.md §10 — applied AFTER the DP release, so ε is unchanged).
 
+The first line printed names the device JAX chose. To require the chip,
+set ``JAX_PLATFORMS=tpu``: JAX then fails at start-up instead of falling
+back to the CPU, where the Pallas kernels would run in interpret mode.
+
 Examples:
   PYTHONPATH=src python -m repro.launch.train --arch qwen2-0.5b --smoke \
       --trainer fedavg --rounds 3 --local-steps 2 --agg fedavgm
@@ -70,6 +74,7 @@ from repro.data.lm_data import synthetic_lm_batches
 from repro.models import init_params
 from repro.optim import adam
 from repro.utils.pytree import tree_count_params
+from repro.utils.runtime import device_info, enable_compile_cache
 
 
 def _stack_client_batches(it, clients: int, steps: int):
@@ -145,6 +150,8 @@ def main() -> None:
     ap.add_argument("--multi-krum-m", type=int, default=3,
                     help="rows averaged by --agg multi_krum")
     args = ap.parse_args()
+    print("device:", device_info())
+    enable_compile_cache()
 
     agg_cfg = AggConfig(name=args.agg, server_lr=args.server_lr,
                         momentum=args.server_momentum,

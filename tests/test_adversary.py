@@ -261,13 +261,15 @@ def test_stage_list_shared_across_engines():
 # ---------------------------------------------------------------------------
 def test_attack_off_round_is_deterministic_and_engine_invariant():
     """The benign default pins the pre-§13 numerics: scan and loop agree
-    bit-for-bit, and reruns reproduce exactly."""
+    to float rounding (XLA fuses the two programs differently, so the
+    last ulp may differ), and reruns reproduce exactly."""
     data = _data()
     fcfg = FedConfig(num_clients=6, rounds=3, local_epochs=2,
                      num_context=3, num_target=3, eval_every=10)
     h_scan = _run(fcfg, "scan", data)
     h_loop = _run(fcfg, "loop", data)
-    np.testing.assert_array_equal(h_scan.round_loss, h_loop.round_loss)
+    np.testing.assert_allclose(h_scan.round_loss, h_loop.round_loss,
+                               rtol=1e-6, atol=0)
     np.testing.assert_array_equal(h_scan.round_loss,
                                   _run(fcfg, "scan", data).round_loss)
 
@@ -287,7 +289,8 @@ def test_attacked_round_scan_matches_loop(kind, aggname):
                                    multi_krum_m=3))
     h_scan = _run(fcfg, "scan", data)
     h_loop = _run(fcfg, "loop", data)
-    np.testing.assert_array_equal(h_scan.round_loss, h_loop.round_loss)
+    np.testing.assert_allclose(h_scan.round_loss, h_loop.round_loss,
+                               rtol=1e-6, atol=0)
     # the attack visibly perturbed the trajectory
     clean = FedConfig(num_clients=6, rounds=3, local_epochs=2,
                       num_context=3, num_target=3, eval_every=10)

@@ -344,18 +344,26 @@ def test_faulty_runs_stay_finite_per_strategy(name):
 # ---------------------------------------------------------------------------
 def test_ef_residual_frozen_for_lost_clients():
     avail = AvailabilityConfig(online_prob=0.8, crash_prob=0.4)
-    fed = _make_fed(avail=avail, seed=3,
+
+    def first_round_keep(seed, num_clients):
+        # host replay of the round's schedule (same key chain as the driver)
+        key = jax.random.PRNGKey(seed + 1)
+        _, k_round, _ = jax.random.split(key, 3)
+        sched = av.round_schedule(av.fold_fault_key(k_round),
+                                  av.init_fault_state(num_clients, 1), avail,
+                                  num_clients)
+        return np.asarray(sched.fresh | sched.straggle)
+
+    # the first seed whose first round both keeps and loses clients (the
+    # schedule is a pure function of the seed, but which seed mixes the
+    # two cases depends on JAX's PRNG stream)
+    C = len(_make_fed().train_groups)
+    seed = next(s for s in range(3, 64)
+                if 0 < first_round_keep(s, C).sum() < C)
+    fed = _make_fed(avail=avail, seed=seed,
                     compression=CompressionConfig(kind="int8"))
     fed.run(rounds=1, engine="loop")
-    # host replay of the round's schedule (same key chain as the driver)
-    key = jax.random.PRNGKey(fed.fed_cfg.seed + 1)
-    _, k_round, _ = jax.random.split(key, 3)
-    fkey = av.fold_fault_key(k_round)
-    C = len(fed.train_groups)
-    sched = av.round_schedule(
-        fkey, av.init_fault_state(C, 1), avail, C)
-    keep = np.asarray(sched.fresh | sched.straggle)
-    assert keep.any() and not keep.all()  # both cases occur at seed 3
+    keep = first_round_keep(seed, C)
     resid = np.asarray(fed.ef_resid)
     row_active = np.abs(resid).max(axis=1) > 0
     # releasing clients accumulated quantization error; lost clients'

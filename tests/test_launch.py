@@ -115,6 +115,27 @@ def test_collective_regex():
     assert stats.bytes_by_kind["reduce-scatter"] == 2 * 8 * 8 * 4
 
 
+def test_collective_regex_counts_opcodes_not_operand_names():
+    """A tuple all-reduce is read back through get-tuple-element lines
+    that name it as an operand; only the collective itself moves bytes.
+    An async pair counts once, at its -done. TPU tile layouts put
+    parentheses inside a tuple result."""
+    text = """
+  %all-reduce.1 = (f32[32]{0}, f32[18,32]{1,0}) all-reduce(%a, %b), to_apply=%r
+  %get-tuple-element.3 = f32[32]{0} get-tuple-element(%all-reduce.1), index=0
+  %get-tuple-element.4 = f32[18,32]{1,0} get-tuple-element(%all-reduce.1), index=1
+  %all-gather-start = (f32[8]{0}, f32[64]{0}) all-gather-start(%c), dimensions={0}
+  %all-gather-done = f32[64]{0} all-gather-done(%all-gather-start)
+  %fusion.2 = f32[64]{0} fusion(%all-gather-done), kind=kLoop
+  %all-reduce.14 = (f32[128]{0:T(128)S(1)}, f32[4,128]{1,0:T(4,128)S(1)}) all-reduce(%p, %q), channel_id=1
+"""
+    stats = parse_collectives(text)
+    assert stats.bytes_by_kind == {
+        "all-reduce": (32 + 18 * 32 + 128 + 4 * 128) * 4,
+        "all-gather": 64 * 4}
+    assert stats.count_by_kind == {"all-reduce": 2, "all-gather": 1}
+
+
 def test_model_flops_moe_counts_active_only():
     dense = get_arch("qwen2-0.5b")
     moe = get_arch("grok-1-314b")

@@ -3,6 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.mesh import make_host_mesh
 from repro.models.partitioning import activation_sharding, default_rules, shard_act
 
 
@@ -13,7 +14,7 @@ def test_identity_without_context(rng):
 
 
 def test_with_single_device_mesh(rng):
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh(1, 1)
     x = jax.random.normal(rng, (4, 6, 8))
 
     @jax.jit
@@ -25,7 +26,7 @@ def test_with_single_device_mesh(rng):
 
 
 def test_divisibility_guard():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh(1, 1)
     rules = default_rules(mesh)
     assert rules["heads"] == "model"
     # dims not divisible by the axis are left unsharded -> no error
@@ -36,7 +37,7 @@ def test_divisibility_guard():
 
 
 def test_rank_mismatch_is_noop(rng):
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh(1, 1)
     x = jax.random.normal(rng, (4, 8))
     with activation_sharding(mesh):
         y = shard_act(x, ("batch", "seq", "heads"))  # wrong rank
