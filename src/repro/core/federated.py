@@ -41,7 +41,10 @@ Two execution engines expose the same round semantics:
   (``benchmarks/bench_round.py``) and equivalence tests.
 
 Both drivers derive per-round RNG keys identically, so they produce the
-same ``History`` up to float reassociation.
+same ``History`` up to float reassociation. The round-key chain is the
+trainer's: it starts at ``PRNGKey(seed + 1)`` and every ``run`` call
+continues it, so ``run(10)`` twice trains on the same draws as
+``run(20)``.
 """
 from __future__ import annotations
 
@@ -76,6 +79,7 @@ from repro.utils.pytree import (
     tree_sub,
     tree_unflatten_from_vector,
 )
+from repro.utils.spans import span
 
 PyTree = Any
 
@@ -219,6 +223,8 @@ class FederatedGPO:
 
         key = jax.random.PRNGKey(fed_cfg.seed)
         self.global_params = init_gpo_params(gpo_cfg, key)
+        # the round-key chain, carried across ``run`` calls
+        self.round_key = jax.random.PRNGKey(fed_cfg.seed + 1)
         self.server_state = self.agg.init(self.global_params)
         # EF21-style compression residual (DESIGN.md §10): one flat f32
         # row per client, carried across rounds next to the server state
@@ -301,8 +307,9 @@ class FederatedGPO:
             train_args = (client_params, opt_sub, keys, groups)
             if pipe.flip_data:
                 train_args += (pipe.attacked_flags(bk, idx),)
-            new_client_params, opt_sub, losses = jax.vmap(local_train)(
-                *train_args)
+            with jax.named_scope("local_train"):
+                new_client_params, opt_sub, losses = jax.vmap(local_train)(
+                    *train_args)
             opt_states = jax.tree.map(
                 lambda full, sub: full.at[idx].set(sub), opt_states,
                 opt_sub)
@@ -322,8 +329,9 @@ class FederatedGPO:
 
         def eval_fn(global_params, key):
             keys = jax.random.split(key, len(eval_groups))
-            return jax.vmap(eval_group, in_axes=(None, 0, 0))(
-                global_params, keys, self.eval_groups)
+            with jax.named_scope("eval"):
+                return jax.vmap(eval_group, in_axes=(None, 0, 0))(
+                    global_params, keys, self.eval_groups)
 
         num_eval = len(eval_groups)
 
@@ -409,8 +417,9 @@ class FederatedGPO:
             train_args = (client_params, opt_sub, keys, groups)
             if pipe.flip_data:
                 train_args += (pipe.attacked_flags(bk, idx),)
-            new_client_params, opt_sub, losses = jax.vmap(local_train)(
-                *train_args)
+            with jax.named_scope("local_train"):
+                new_client_params, opt_sub, losses = jax.vmap(local_train)(
+                    *train_args)
             # opt states advance only where the round's local work
             # survived: offline clients never trained, crashed clients
             # lost theirs with the crash
@@ -473,9 +482,10 @@ class FederatedGPO:
                 # adaptive: the server only observed losses that arrived
                 # with a fresh release
                 kw["mask"] = sched.fresh[idx]
-            new_global, new_state = agg.apply(
-                server_state, global_params, delta, losses=losses,
-                idx=idx, **kw)
+            with pipe.scope("aggregate"):
+                new_global, new_state = agg.apply(
+                    server_state, global_params, delta, losses=losses,
+                    idx=idx, **kw)
             # zero-survivor round: verified no-op on params AND AggState
             new_global = av.tree_where(any_surv, new_global, global_params)
             server_state = av.tree_where(any_surv, new_state, server_state)
@@ -570,9 +580,13 @@ class FederatedGPO:
         raise ValueError(f"unknown engine {engine!r} (want 'scan'|'loop')")
 
     def _run_scan(self, rounds: int, log_every: int) -> History:
-        fed = self.fed_cfg
+        """The fused driver. Host spans (``utils/spans.py``): ``fed.run``
+        around the call, ``fed.block`` per block with its ``fed.dispatch``
+        (the mask's transfer and the block's enqueue), ``fed.fetch``
+        (losses and scores to the host, which waits on the device) and
+        ``fed.record`` (``History``, privacy accounting, eval metrics,
+        log line); ``fed.round`` per round of a sub-block tail."""
         eval_mask = self._eval_mask(rounds)
-        key = jax.random.PRNGKey(fed.seed + 1)
         hist = History()
         # one fused block normally; with log_every, blocks of log_every
         # rounds so progress still reaches the console while training
@@ -580,62 +594,79 @@ class FederatedGPO:
         # does not change any per-round key).
         chunk = min(log_every, rounds) if log_every else rounds
         full_end = (rounds // chunk) * chunk
-        for start in range(0, full_end, chunk):
-            mask = eval_mask[start:start + chunk]
+        with span("fed.run", rounds=rounds):
+            for start in range(0, full_end, chunk):
+                mask = eval_mask[start:start + chunk]
+                with span("fed.block", rounds=len(mask)):
+                    self._run_block(hist, mask, log_every)
+            # remainder shorter than a chunk: run per-round (same key
+            # chain) rather than compiling the fused block a second time
+            # for a tail
+            for r in range(full_end, rounds):
+                self._dispatch_round(hist, r, eval_mask, log_every)
+        return hist
+
+    def _run_block(self, hist: History, mask: np.ndarray,
+                   log_every: int) -> None:
+        """One fused block of ``len(mask)`` rounds, appended to ``hist``."""
+        with span("fed.dispatch"):
             try:
                 if self._faults:
                     (self.global_params, self.opt_states, self.ef_resid,
-                     self.fault_state, self.server_state, key, losses,
-                     scores, n_rel) = self._block(
+                     self.fault_state, self.server_state, self.round_key,
+                     losses, scores, n_rel) = self._block(
                         self.global_params, self.opt_states, self.ef_resid,
-                        self.fault_state, self.server_state, key,
+                        self.fault_state, self.server_state, self.round_key,
                         jnp.asarray(mask))
-                    hist.round_survivors.extend(
-                        int(x) for x in np.asarray(n_rel))
                 else:
                     (self.global_params, self.opt_states, self.ef_resid,
-                     self.server_state, key, losses, scores) = self._block(
+                     self.server_state, self.round_key, losses,
+                     scores) = self._block(
                         self.global_params, self.opt_states, self.ef_resid,
-                        self.server_state, key, jnp.asarray(mask))
+                        self.server_state, self.round_key, jnp.asarray(mask))
+                    n_rel = None
             except BaseException:
                 self._recover_donated_opt_states()
                 raise
-            base = len(hist.round_loss)
-            hist.round_loss.extend(float(x) for x in np.asarray(losses))
-            self._note_privacy(hist, len(mask))
+        with span("fed.fetch"):
+            losses = np.asarray(losses)
             scores = np.asarray(scores)  # (chunk, K); valid where mask
+            if n_rel is not None:
+                n_rel = np.asarray(n_rel)
+        with span("fed.record"):
+            base = len(hist.round_loss)
+            if n_rel is not None:
+                hist.round_survivors.extend(int(x) for x in n_rel)
+            hist.round_loss.extend(float(x) for x in losses)
+            self._note_privacy(hist, len(mask))
             for r in np.nonzero(mask)[0]:
                 self._append_eval(hist, base + int(r), scores[r], log_every)
-        # remainder shorter than a chunk: run per-round (same key chain)
-        # rather than compiling the fused block a second time for a tail
-        for r in range(full_end, rounds):
-            key = self._dispatch_round(hist, key, r, eval_mask, log_every)
-        return hist
 
-    def _dispatch_round(self, hist: History, key, r: int, eval_mask,
-                        log_every: int):
+    def _dispatch_round(self, hist: History, r: int, eval_mask,
+                        log_every: int) -> None:
         """One per-round dispatch + metric append; shared by the loop
-        driver and the scan driver's sub-chunk tail. Returns the carried
+        driver and the scan driver's sub-chunk tail. Advances the carried
         key (chain identical to one scan step)."""
-        key, k_round, k_eval = jax.random.split(key, 3)
-        if self._faults:
-            (self.global_params, self.opt_states, self.server_state,
-             self.ef_resid, self.fault_state, loss, n_rel) = self._round(
-                self.global_params, self.opt_states, self.server_state,
-                self.ef_resid, self.fault_state, k_round)
-            hist.round_loss.append(float(loss))
-            hist.round_survivors.append(int(n_rel))
-        else:
-            (self.global_params, self.opt_states, self.server_state,
-             self.ef_resid, losses) = self._round(
-                self.global_params, self.opt_states, self.server_state,
-                self.ef_resid, k_round)
-            hist.round_loss.append(float(jnp.mean(losses)))
-        self._note_privacy(hist, 1)
-        if eval_mask[r]:
-            scores = np.asarray(self._eval(self.global_params, k_eval))
-            self._append_eval(hist, r, scores, log_every)
-        return key
+        with span("fed.round"):
+            self.round_key, k_round, k_eval = jax.random.split(
+                self.round_key, 3)
+            if self._faults:
+                (self.global_params, self.opt_states, self.server_state,
+                 self.ef_resid, self.fault_state, loss, n_rel) = self._round(
+                    self.global_params, self.opt_states, self.server_state,
+                    self.ef_resid, self.fault_state, k_round)
+                hist.round_loss.append(float(loss))
+                hist.round_survivors.append(int(n_rel))
+            else:
+                (self.global_params, self.opt_states, self.server_state,
+                 self.ef_resid, losses) = self._round(
+                    self.global_params, self.opt_states, self.server_state,
+                    self.ef_resid, k_round)
+                hist.round_loss.append(float(jnp.mean(losses)))
+            self._note_privacy(hist, 1)
+            if eval_mask[r]:
+                scores = np.asarray(self._eval(self.global_params, k_eval))
+                self._append_eval(hist, r, scores, log_every)
 
     def _recover_donated_opt_states(self) -> None:
         """After an interrupted block call the donated opt buffers may be
@@ -667,10 +698,9 @@ class FederatedGPO:
 
     def _run_loop(self, rounds: int, log_every: int) -> History:
         hist = History()
-        key = jax.random.PRNGKey(self.fed_cfg.seed + 1)
         eval_mask = self._eval_mask(rounds)  # shared cadence, both drivers
         for r in range(rounds):
-            key = self._dispatch_round(hist, key, r, eval_mask, log_every)
+            self._dispatch_round(hist, r, eval_mask, log_every)
         return hist
 
 
@@ -772,7 +802,8 @@ def make_sharded_round(gpo_cfg: GPOConfig, fed_cfg: FedConfig,
         train_args = (client_params, opt_states, keys, group_ids)
         if pipe.flip_data:
             train_args += (pipe.attacked_flags(byz_key, gids),)
-        new_params, new_opt, losses = jax.vmap(local_train)(*train_args)
+        with jax.named_scope("local_train"):
+            new_params, new_opt, losses = jax.vmap(local_train)(*train_args)
         # delta contract: entry params ARE the replicated global model
         deltas = tree_sub(new_params, client_params)
         global_prev = tree_index(client_params, 0)
@@ -821,7 +852,8 @@ def make_sharded_round(gpo_cfg: GPOConfig, fed_cfg: FedConfig,
         train_args = (client_params, opt_states, keys, group_ids)
         if pipe.flip_data:
             train_args += (pipe.attacked_flags(byz_key, gids),)
-        new_params, new_opt, losses = jax.vmap(local_train)(*train_args)
+        with jax.named_scope("local_train"):
+            new_params, new_opt, losses = jax.vmap(local_train)(*train_args)
         deltas = tree_sub(new_params, client_params)
         global_prev = tree_index(client_params, 0)
         fresh_l = sched.fresh[gids]

@@ -27,6 +27,9 @@ from repro.kernels.quant_matmul import QuantizedLinear
 from repro.models.layers import dense_init, rms_norm
 
 NEG_INF = -1e30
+# named scope of the attention in every forward (dense einsums and the
+# Pallas kernel alike), so a profile finds it by one name
+ATTENTION_SCOPE = "gpo_attention"
 
 
 def _mm(x, w):
@@ -120,21 +123,22 @@ def gpo_apply(params: dict, cfg: GPOConfig, ctx_x, ctx_y, tgt_x):
         q = _mm(h, layer.wq).reshape(s, nh, h_dim)
         k = _mm(h, layer.wk).reshape(s, nh, h_dim)
         v = _mm(h, layer.wv).reshape(s, nh, h_dim)
-        if cfg.use_pallas_attention:
-            # banded flash kernel with a custom VJP (DESIGN.md §4, §8):
-            # valid under jax.grad, so training (gpo_loss) and inference
-            # share the same tiled path — the dense (heads, S, S) score
-            # tensor below is never materialized.
-            from repro.kernels import gpo_attention
+        with jax.named_scope(ATTENTION_SCOPE):
+            if cfg.use_pallas_attention:
+                # banded flash kernel with a custom VJP (DESIGN.md §4, §8):
+                # valid under jax.grad, so training (gpo_loss) and
+                # inference share the same tiled path — the dense (heads,
+                # S, S) score tensor below is never materialized.
+                from repro.kernels import gpo_attention
 
-            att = gpo_attention(q, k, v, num_ctx=m).reshape(s, -1)
-        else:
-            scores = jnp.einsum("ihd,jhd->hij", q, k) / jnp.sqrt(
-                jnp.asarray(h_dim, jnp.float32))
-            scores = jnp.where(_np_mask(m, t)[None], scores, NEG_INF)
-            probs = jax.nn.softmax(scores.astype(jnp.float32),
-                                   axis=-1).astype(v.dtype)
-            att = jnp.einsum("hij,jhd->ihd", probs, v).reshape(s, -1)
+                att = gpo_attention(q, k, v, num_ctx=m).reshape(s, -1)
+            else:
+                scores = jnp.einsum("ihd,jhd->hij", q, k) / jnp.sqrt(
+                    jnp.asarray(h_dim, jnp.float32))
+                scores = jnp.where(_np_mask(m, t)[None], scores, NEG_INF)
+                probs = jax.nn.softmax(scores.astype(jnp.float32),
+                                       axis=-1).astype(v.dtype)
+                att = jnp.einsum("hij,jhd->ihd", probs, v).reshape(s, -1)
         x = x + _mm(att, layer.wo)
         h2 = rms_norm(x, layer.ln2, cfg.norm_eps)
         x = x + _mm(jax.nn.gelu(_mm(h2, layer.w1)), layer.w2)
@@ -199,13 +203,14 @@ def gpo_prefill(params: dict, cfg: GPOConfig, ctx_x, ctx_y,
         q = _mm(h, layer.wq).reshape(m, nh, h_dim)
         k = _mm(h, layer.wk).reshape(m, nh, h_dim)
         v = _mm(h, layer.wv).reshape(m, nh, h_dim)
-        scores = jnp.einsum("ihd,jhd->hij", q, k) / jnp.sqrt(
-            jnp.asarray(h_dim, jnp.float32))
-        if mask is not None:
-            scores = jnp.where(mask[None, None, :], scores, NEG_INF)
-        probs = jax.nn.softmax(scores.astype(jnp.float32),
-                               axis=-1).astype(v.dtype)
-        att = jnp.einsum("hij,jhd->ihd", probs, v).reshape(m, -1)
+        with jax.named_scope(ATTENTION_SCOPE):
+            scores = jnp.einsum("ihd,jhd->hij", q, k) / jnp.sqrt(
+                jnp.asarray(h_dim, jnp.float32))
+            if mask is not None:
+                scores = jnp.where(mask[None, None, :], scores, NEG_INF)
+            probs = jax.nn.softmax(scores.astype(jnp.float32),
+                                   axis=-1).astype(v.dtype)
+            att = jnp.einsum("hij,jhd->ihd", probs, v).reshape(m, -1)
         x = x + _mm(att, layer.wo)
         h2 = rms_norm(x, layer.ln2, cfg.norm_eps)
         x = x + _mm(jax.nn.gelu(_mm(h2, layer.w1)), layer.w2)
@@ -243,18 +248,19 @@ def gpo_decode(params: dict, cfg: GPOConfig, prefix: GPOPrefix, tgt_x,
         q = _mm(h, layer.wq).reshape(t, nh, h_dim)
         k_self = _mm(h, layer.wk).reshape(t, nh, h_dim)
         v_self = _mm(h, layer.wv).reshape(t, nh, h_dim)
-        inv_sqrt = 1.0 / jnp.sqrt(jnp.asarray(h_dim, jnp.float32))
-        sc_ctx = jnp.einsum("ihd,jhd->hij", q, kc) * inv_sqrt  # (h, T, M)
-        sc_self = jnp.sum(q * k_self, axis=-1).T[:, :, None] * inv_sqrt
-        scores = jnp.concatenate([sc_ctx, sc_self], axis=-1)  # (h, T, M+1)
-        if mask is not None:
-            full = jnp.concatenate(
-                [mask, jnp.ones((1,), bool)])  # self always attends
-            scores = jnp.where(full[None, None, :], scores, NEG_INF)
-        probs = jax.nn.softmax(scores.astype(jnp.float32),
-                               axis=-1).astype(v_self.dtype)
-        att = (jnp.einsum("hij,jhd->ihd", probs[..., :mctx], vc)
-               + probs[..., mctx:].transpose(1, 0, 2) * v_self)
+        with jax.named_scope(ATTENTION_SCOPE):
+            inv_sqrt = 1.0 / jnp.sqrt(jnp.asarray(h_dim, jnp.float32))
+            sc_ctx = jnp.einsum("ihd,jhd->hij", q, kc) * inv_sqrt
+            sc_self = jnp.sum(q * k_self, axis=-1).T[:, :, None] * inv_sqrt
+            scores = jnp.concatenate([sc_ctx, sc_self], axis=-1)  # (h,T,M+1)
+            if mask is not None:
+                full = jnp.concatenate(
+                    [mask, jnp.ones((1,), bool)])  # self always attends
+                scores = jnp.where(full[None, None, :], scores, NEG_INF)
+            probs = jax.nn.softmax(scores.astype(jnp.float32),
+                                   axis=-1).astype(v_self.dtype)
+            att = (jnp.einsum("hij,jhd->ihd", probs[..., :mctx], vc)
+                   + probs[..., mctx:].transpose(1, 0, 2) * v_self)
         x = x + _mm(att.reshape(t, -1), layer.wo)
         h2 = rms_norm(x, layer.ln2, cfg.norm_eps)
         x = x + _mm(jax.nn.gelu(_mm(h2, layer.w1)), layer.w2)

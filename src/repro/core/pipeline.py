@@ -49,6 +49,7 @@ per-client released rows between the stages.
 """
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass
 from typing import Any, Optional
 
@@ -135,6 +136,16 @@ class RoundPipeline:
             ("aggregate", True),
         )
 
+    def scope(self, *stages):
+        """``jax.named_scope`` over the computation of ``stages``, named
+        by those of them that are enabled, joined by ``+`` where one fused
+        computation runs several (``privacy+codec+aggregate``), so a
+        profile finds each stage's operations by name; a no-op when none
+        of them is enabled."""
+        on = dict(self.stages())
+        name = "+".join(s for s in stages if on[s])
+        return jax.named_scope(name) if name else contextlib.nullcontext()
+
     # -- attack stage ------------------------------------------------------
     def fold_key(self, round_key):
         """Round's Byzantine key (None when the adversary is off, so the
@@ -166,17 +177,18 @@ class RoundPipeline:
         moments then psum across shards so colluding attackers agree."""
         if not self.attack_delta:
             return vecs
-        mask_full = self._mask(byz_key, vecs.shape[0])
-        if gids is None:
-            gids = jnp.arange(vecs.shape[0], dtype=jnp.int32)
-            mask = mask_full
-        else:
-            mask = mask_full[gids]
-        stats = None
-        if axes is not None and self.adversary.kind == "alie":
-            stats = byz.honest_stats_sharded(vecs, mask, axes)
-        return byz.apply_attack(vecs, mask, self.adversary, byz_key,
-                                gids, stats=stats)
+        with jax.named_scope("attack"):
+            mask_full = self._mask(byz_key, vecs.shape[0])
+            if gids is None:
+                gids = jnp.arange(vecs.shape[0], dtype=jnp.int32)
+                mask = mask_full
+            else:
+                mask = mask_full[gids]
+            stats = None
+            if axes is not None and self.adversary.kind == "alie":
+                stats = byz.honest_stats_sharded(vecs, mask, axes)
+            return byz.apply_attack(vecs, mask, self.adversary, byz_key,
+                                    gids, stats=stats)
 
     # -- privacy + codec (per-row release, fault engines) ------------------
     def release_rows(self, vecs, keys, resid, *, byz_key=None, gids=None,
@@ -187,8 +199,9 @@ class RoundPipeline:
         exactly the §11 composition. Attack-off: verbatim
         ``cx.release_flat``."""
         vecs = self.attack_rows(vecs, byz_key, gids, axes=axes)
-        return cx.release_flat(vecs, keys, self.privacy, self.compression,
-                               resid)
+        with self.scope("privacy", "codec"):
+            return cx.release_flat(vecs, keys, self.privacy,
+                                   self.compression, resid)
 
     # -- aggregate stage helpers -------------------------------------------
     def _bound_rows(self, rel):
@@ -281,41 +294,45 @@ class RoundPipeline:
         agg, priv, comp = self.agg, self.privacy, self.compression
         if not self.restructured:
             # pre-§13 dispatch, byte-for-byte (the §9/§10 pins ride it)
-            if comp.enabled:
-                w_eff = agg.weigh(server_state, weights, idx)
-                delta_vec, new_r = cx.transport_delta_flat(
-                    tree_ravel_clients(deltas), w_eff, keys, priv, comp,
-                    agg, resid, use_pallas=self.use_pallas)
-                delta = tree_unflatten_from_vector(delta_vec,
-                                                   global_params)
-                new_global, server_state = agg.apply(
-                    server_state, global_params, delta, losses=losses,
-                    idx=idx)
-                return new_global, server_state, new_r
-            if priv.enabled:
-                w_eff = agg.weigh(server_state, weights, idx)
-                delta_vec = dp.private_delta_flat(
-                    tree_ravel_clients(deltas), w_eff, keys, priv, agg,
-                    use_pallas=self.use_pallas)
-                delta = tree_unflatten_from_vector(delta_vec,
-                                                   global_params)
-                new_global, server_state = agg.apply(
-                    server_state, global_params, delta, losses=losses,
-                    idx=idx)
+            with self.scope("privacy", "codec", "aggregate"):
+                if comp.enabled:
+                    w_eff = agg.weigh(server_state, weights, idx)
+                    delta_vec, new_r = cx.transport_delta_flat(
+                        tree_ravel_clients(deltas), w_eff, keys, priv, comp,
+                        agg, resid, use_pallas=self.use_pallas)
+                    delta = tree_unflatten_from_vector(delta_vec,
+                                                       global_params)
+                    new_global, server_state = agg.apply(
+                        server_state, global_params, delta, losses=losses,
+                        idx=idx)
+                    return new_global, server_state, new_r
+                if priv.enabled:
+                    w_eff = agg.weigh(server_state, weights, idx)
+                    delta_vec = dp.private_delta_flat(
+                        tree_ravel_clients(deltas), w_eff, keys, priv, agg,
+                        use_pallas=self.use_pallas)
+                    delta = tree_unflatten_from_vector(delta_vec,
+                                                       global_params)
+                    new_global, server_state = agg.apply(
+                        server_state, global_params, delta, losses=losses,
+                        idx=idx)
+                    return new_global, server_state, resid
+                new_global, server_state = agg.step(
+                    server_state, global_params, deltas, weights,
+                    losses=losses, idx=idx)
                 return new_global, server_state, resid
-            new_global, server_state = agg.step(
-                server_state, global_params, deltas, weights,
-                losses=losses, idx=idx)
-            return new_global, server_state, resid
         # restructured: materialize attacked/released rows, bound, reduce
-        w_eff = agg.weigh(server_state, weights, idx)
+        with self.scope("aggregate"):
+            w_eff = agg.weigh(server_state, weights, idx)
         vecs = self.attack_rows(tree_ravel_clients(deltas), byz_key, idx)
-        rel, new_r = cx.release_flat(vecs, keys, priv, comp, resid)
-        rel = self._bound_rows(rel)
-        delta = tree_unflatten_from_vector(
-            self.hier_reduce_flat(rel, w_eff), global_params)
-        new_global, server_state = agg.apply(
-            server_state, global_params, delta, losses=losses, idx=idx)
+        with self.scope("privacy", "codec"):
+            rel, new_r = cx.release_flat(vecs, keys, priv, comp, resid)
+        with self.scope("aggregate"):
+            rel = self._bound_rows(rel)
+            delta = tree_unflatten_from_vector(
+                self.hier_reduce_flat(rel, w_eff), global_params)
+            new_global, server_state = agg.apply(
+                server_state, global_params, delta, losses=losses, idx=idx)
         return new_global, server_state, new_r
 
     # -- sharded middle: [attack →] privacy → codec → reduce collective ----
@@ -330,80 +347,81 @@ class RoundPipeline:
         agg, priv, comp = self.agg, self.privacy, self.compression
         ef = comp.enabled and comp.error_feedback
         if not self.restructured:
-            new_resid = None
-            if comp.enabled:
-                vecs = tree_ravel_clients(deltas)
-                if agg.linear:
-                    local_vec, new_resid = cx.transport_delta_flat(
-                        vecs, weights, keys, priv, comp, agg, resid,
-                        use_pallas=self.use_pallas)
-                    delta = tree_unflatten_from_vector(
-                        jax.lax.psum(local_vec, axes), global_prev)
-                else:
-                    x = (dp.privatize_flat(vecs, keys, priv)
-                         if priv.enabled else vecs.astype(jnp.float32))
-                    u = x + resid if ef else x
-                    if comp.kind == "int8":
-                        uniform = (cx.client_uniform(keys, u.shape)
-                                   if comp.stochastic else None)
-                        q, scales = cx.quantize_int8(u, uniform=uniform)
-                        t_local = cx.dequantize_int8(q, scales)
-                        all_q = jax.lax.all_gather(q, axes, axis=0,
+            with self.scope("privacy", "codec", "aggregate"):
+                new_resid = None
+                if comp.enabled:
+                    vecs = tree_ravel_clients(deltas)
+                    if agg.linear:
+                        local_vec, new_resid = cx.transport_delta_flat(
+                            vecs, weights, keys, priv, comp, agg, resid,
+                            use_pallas=self.use_pallas)
+                        delta = tree_unflatten_from_vector(
+                            jax.lax.psum(local_vec, axes), global_prev)
+                    else:
+                        x = (dp.privatize_flat(vecs, keys, priv)
+                             if priv.enabled else vecs.astype(jnp.float32))
+                        u = x + resid if ef else x
+                        if comp.kind == "int8":
+                            uniform = (cx.client_uniform(keys, u.shape)
+                                       if comp.stochastic else None)
+                            q, scales = cx.quantize_int8(u, uniform=uniform)
+                            t_local = cx.dequantize_int8(q, scales)
+                            all_q = jax.lax.all_gather(q, axes, axis=0,
+                                                       tiled=True)
+                            all_s = jax.lax.all_gather(scales, axes, axis=0,
+                                                       tiled=True)
+                            all_vecs = cx.dequantize_int8(all_q, all_s)
+                        else:  # topk: dense f32 layout of the sparse shard
+                            t_local, _ = cx.sparsify_topk(u, comp.topk_frac)
+                            all_vecs = jax.lax.all_gather(t_local, axes,
+                                                          axis=0, tiled=True)
+                        new_resid = u - t_local if ef else None
+                        all_w = jax.lax.all_gather(weights, axes, axis=0,
                                                    tiled=True)
-                        all_s = jax.lax.all_gather(scales, axes, axis=0,
+                        delta = tree_unflatten_from_vector(
+                            agg.reduce_flat(all_vecs, all_w), global_prev)
+                elif priv.enabled:
+                    vecs = tree_ravel_clients(deltas)
+                    if agg.linear:
+                        local_vec = dp.clip_noise_reduce(
+                            vecs, weights, keys, priv,
+                            use_pallas=self.use_pallas)
+                        delta = tree_unflatten_from_vector(
+                            jax.lax.psum(local_vec, axes), global_prev)
+                    else:
+                        pvecs = dp.privatize_flat(vecs, keys, priv)
+                        all_vecs = jax.lax.all_gather(pvecs, axes, axis=0,
+                                                      tiled=True)
+                        all_w = jax.lax.all_gather(weights, axes, axis=0,
                                                    tiled=True)
-                        all_vecs = cx.dequantize_int8(all_q, all_s)
-                    else:  # topk: dense f32 layout of the sparse shard
-                        t_local, _ = cx.sparsify_topk(u, comp.topk_frac)
-                        all_vecs = jax.lax.all_gather(t_local, axes,
-                                                      axis=0, tiled=True)
-                    new_resid = u - t_local if ef else None
-                    all_w = jax.lax.all_gather(weights, axes, axis=0,
-                                               tiled=True)
-                    delta = tree_unflatten_from_vector(
-                        agg.reduce_flat(all_vecs, all_w), global_prev)
-            elif priv.enabled:
-                vecs = tree_ravel_clients(deltas)
-                if agg.linear:
-                    local_vec = dp.clip_noise_reduce(
-                        vecs, weights, keys, priv,
-                        use_pallas=self.use_pallas)
-                    delta = tree_unflatten_from_vector(
-                        jax.lax.psum(local_vec, axes), global_prev)
+                        delta = tree_unflatten_from_vector(
+                            agg.reduce_flat(all_vecs, all_w), global_prev)
+                elif agg.linear:
+                    if self.use_pallas:
+                        vecs = tree_ravel_clients(deltas)
+                        local_vec = fedavg_reduce(
+                            vecs, weights.astype(jnp.float32))
+                        delta = tree_unflatten_from_vector(
+                            jax.lax.psum(local_vec, axes), global_prev)
+                    else:
+                        local_weighted = jax.tree.map(
+                            lambda x: jnp.sum(
+                                x.astype(jnp.float32)
+                                * weights.reshape(
+                                    (-1,) + (1,) * (x.ndim - 1)),
+                                axis=0),
+                            deltas)
+                        delta = fedavg_allreduce(
+                            local_weighted, jnp.asarray(1.0, jnp.float32),
+                            axes)
                 else:
-                    pvecs = dp.privatize_flat(vecs, keys, priv)
-                    all_vecs = jax.lax.all_gather(pvecs, axes, axis=0,
+                    vecs = tree_ravel_clients(deltas)
+                    all_vecs = jax.lax.all_gather(vecs, axes, axis=0,
                                                   tiled=True)
                     all_w = jax.lax.all_gather(weights, axes, axis=0,
                                                tiled=True)
                     delta = tree_unflatten_from_vector(
                         agg.reduce_flat(all_vecs, all_w), global_prev)
-            elif agg.linear:
-                if self.use_pallas:
-                    vecs = tree_ravel_clients(deltas)
-                    local_vec = fedavg_reduce(
-                        vecs, weights.astype(jnp.float32))
-                    delta = tree_unflatten_from_vector(
-                        jax.lax.psum(local_vec, axes), global_prev)
-                else:
-                    local_weighted = jax.tree.map(
-                        lambda x: jnp.sum(
-                            x.astype(jnp.float32)
-                            * weights.reshape(
-                                (-1,) + (1,) * (x.ndim - 1)),
-                            axis=0),
-                        deltas)
-                    delta = fedavg_allreduce(
-                        local_weighted, jnp.asarray(1.0, jnp.float32),
-                        axes)
-            else:
-                vecs = tree_ravel_clients(deltas)
-                all_vecs = jax.lax.all_gather(vecs, axes, axis=0,
-                                              tiled=True)
-                all_w = jax.lax.all_gather(weights, axes, axis=0,
-                                           tiled=True)
-                delta = tree_unflatten_from_vector(
-                    agg.reduce_flat(all_vecs, all_w), global_prev)
             return delta, new_resid
         # restructured: attack + release stay shard-local (the corrupt
         # rows cross the wire like honest ones); the norm bound clips
@@ -413,20 +431,25 @@ class RoundPipeline:
         # forgoing the int8 wire layout under an active attack).
         vecs = self.attack_rows(tree_ravel_clients(deltas), byz_key,
                                 gids, axes=axes)
-        rel, new_resid = cx.release_flat(vecs, keys, priv, comp, resid)
-        rel = self._bound_rows(rel)
-        if agg.linear:
-            # ONE weighted psum over ALL client axes — on an ('edge',
-            # 'data') mesh this IS the composed two-hop partial-sum
-            # schedule (§14: the linear family's bytes are unchanged by
-            # the hierarchy)
-            delta_vec = jax.lax.psum(agg.reduce_flat(rel, weights), axes)
-        elif self.hierarchy.enabled and len(axes) > 1:
-            delta_vec = self._two_hop_reduce(rel, weights, axes)
-        else:
-            all_vecs = jax.lax.all_gather(rel, axes, axis=0, tiled=True)
-            all_w = jax.lax.all_gather(weights, axes, axis=0, tiled=True)
-            delta_vec = agg.reduce_flat(all_vecs, all_w)
+        with self.scope("privacy", "codec"):
+            rel, new_resid = cx.release_flat(vecs, keys, priv, comp, resid)
+        with self.scope("aggregate"):
+            rel = self._bound_rows(rel)
+            if agg.linear:
+                # ONE weighted psum over ALL client axes — on an ('edge',
+                # 'data') mesh this IS the composed two-hop partial-sum
+                # schedule (§14: the linear family's bytes are unchanged
+                # by the hierarchy)
+                delta_vec = jax.lax.psum(agg.reduce_flat(rel, weights),
+                                         axes)
+            elif self.hierarchy.enabled and len(axes) > 1:
+                delta_vec = self._two_hop_reduce(rel, weights, axes)
+            else:
+                all_vecs = jax.lax.all_gather(rel, axes, axis=0,
+                                              tiled=True)
+                all_w = jax.lax.all_gather(weights, axes, axis=0,
+                                           tiled=True)
+                delta_vec = agg.reduce_flat(all_vecs, all_w)
         return (tree_unflatten_from_vector(delta_vec, global_prev),
                 new_resid if ef else None)
 
@@ -440,14 +463,15 @@ class RoundPipeline:
         bound clips the blended rows — what the server is about to
         absorb — first."""
         agg = self.agg
-        contrib = self._bound_rows(contrib)
-        if agg.linear:
-            wn = av.masked_mean_weights(w_c, mask_c)
-            return agg.reduce_flat(contrib, wn)
-        if agg.name in ("median", "trimmed_mean"):
-            return av.masked_robust_reduce_flat(
-                contrib, w_c, mask_c, name=agg.name, trim_frac=trim_frac)
-        return agg.reduce_flat(contrib, jnp.where(mask_c, w_c, 0.0))
+        with self.scope("aggregate"):
+            contrib = self._bound_rows(contrib)
+            if agg.linear:
+                wn = av.masked_mean_weights(w_c, mask_c)
+                return agg.reduce_flat(contrib, wn)
+            if agg.name in ("median", "trimmed_mean"):
+                return av.masked_robust_reduce_flat(
+                    contrib, w_c, mask_c, name=agg.name, trim_frac=trim_frac)
+            return agg.reduce_flat(contrib, jnp.where(mask_c, w_c, 0.0))
 
     def masked_reduce_sharded(self, contrib_l, w_c, mask_c, gids, axes, *,
                               trim_frac):
@@ -455,19 +479,22 @@ class RoundPipeline:
         the shard-local partial sum + ONE psum; robust/defense families
         all-gather the blended rows and reduce replicated."""
         agg = self.agg
-        contrib_l = self._bound_rows(contrib_l)
-        if agg.linear:
-            wn_l = av.masked_mean_weights(w_c, mask_c)[gids]
-            if self.use_pallas:
-                local_vec = fedavg_reduce(contrib_l, wn_l)
-            else:
-                local_vec = jnp.einsum("c,cp->p", wn_l, contrib_l)
-            return jax.lax.psum(local_vec, axes)
-        all_vecs = jax.lax.all_gather(contrib_l, axes, axis=0, tiled=True)
-        if agg.name in ("median", "trimmed_mean"):
-            return av.masked_robust_reduce_flat(
-                all_vecs, w_c, mask_c, name=agg.name, trim_frac=trim_frac)
-        return agg.reduce_flat(all_vecs, jnp.where(mask_c, w_c, 0.0))
+        with self.scope("aggregate"):
+            contrib_l = self._bound_rows(contrib_l)
+            if agg.linear:
+                wn_l = av.masked_mean_weights(w_c, mask_c)[gids]
+                if self.use_pallas:
+                    local_vec = fedavg_reduce(contrib_l, wn_l)
+                else:
+                    local_vec = jnp.einsum("c,cp->p", wn_l, contrib_l)
+                return jax.lax.psum(local_vec, axes)
+            all_vecs = jax.lax.all_gather(contrib_l, axes, axis=0,
+                                          tiled=True)
+            if agg.name in ("median", "trimmed_mean"):
+                return av.masked_robust_reduce_flat(
+                    all_vecs, w_c, mask_c, name=agg.name,
+                    trim_frac=trim_frac)
+            return agg.reduce_flat(all_vecs, jnp.where(mask_c, w_c, 0.0))
 
 
 def make_pipeline(fed_cfg, *, agg: ServerAggregator,

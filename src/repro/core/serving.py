@@ -52,6 +52,7 @@ import numpy as np
 from repro.configs.base import GPOConfig, ServeConfig
 from repro.core.gpo import GPOLayer, GPOPrefix, gpo_decode, gpo_prefill
 from repro.kernels import quantize_linear
+from repro.utils.spans import span
 
 PyTree = Any
 
@@ -131,6 +132,8 @@ class BatchRecord:
     ctx_bucket: int
     tgt_bucket: int
     hits: Tuple[bool, ...]
+    # (ctx bucket, padded group size, contexts) per prefill dispatch
+    prefills: Tuple[Tuple[int, int, int], ...] = ()
 
 
 @dataclass
@@ -152,9 +155,10 @@ class ServeStats:
 def _prefill_batch(params, cfg: GPOConfig, ctx_x, ctx_y, ctx_len):
     """(B, M, d), (B, M), (B,) -> stacked GPOPrefix with (B, L, M, nh, hd)
     K/V."""
-    return jax.vmap(
-        lambda cx, cy, cl: gpo_prefill(params, cfg, cx, cy, ctx_len=cl)
-    )(ctx_x, ctx_y, ctx_len)
+    with jax.named_scope("prefill"):
+        return jax.vmap(
+            lambda cx, cy, cl: gpo_prefill(params, cfg, cx, cy, ctx_len=cl)
+        )(ctx_x, ctx_y, ctx_len)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "num_options"))
@@ -168,7 +172,8 @@ def _decode_batch(params, cfg: GPOConfig, num_options: int,
         scores = jnp.clip(mu.reshape(-1, num_options), 1e-4, None)
         return scores / scores.sum(axis=-1, keepdims=True)
 
-    return jax.vmap(one)(pk, pv, ctx_len, tgt_x)
+    with jax.named_scope("decode"):
+        return jax.vmap(one)(pk, pv, ctx_len, tgt_x)
 
 
 def _bucket_of(n: int, buckets: Sequence[int], what: str) -> int:
@@ -267,7 +272,108 @@ class PreferenceServer:
         while live requests keep strict FIFO order (the no-reorder
         determinism contract): under overload this sheds exactly the
         work nobody is waiting for instead of letting it consume batch
-        slots or return results after their deadline."""
+        slots or return results after their deadline.
+
+        Each phase is a host span (``utils/spans.py``) under
+        ``serve.step``: ``serve.admit``, one ``serve.prefill`` per
+        context-bucket group, ``serve.gather``, ``serve.decode`` (the
+        dispatch alone), ``serve.wait`` and ``serve.complete``. Prefill
+        and decode count their real rows (``rows``) and the padded rows
+        they compute (``computed``)."""
+        with span("serve.step", batch=len(self.batches)) as step_span:
+            with span("serve.admit"):
+                batch = self._admit()
+            step_span.set(requests=len(batch[0]) if batch else 0)
+            if batch is None:
+                return []
+            reqs, ctx_b, tgt_b, batch_b, hits, entries, by_bucket = batch
+            take = len(reqs)
+
+            # batched prefill of the misses, grouped by own ctx bucket
+            fresh: dict = {}
+            prefills = []
+            for b, group in sorted(by_bucket.items()):
+                gb = _bucket_of(len(group), self.scfg.batch_buckets, "batch")
+                lens = np.array([r.ctx_x.shape[0] for r in group], np.int32)
+                with span("serve.prefill", contexts=len(group),
+                          rows=int(lens.sum()), computed=gb * b):
+                    cxs = np.zeros((gb, b, group[0].ctx_x.shape[1]),
+                                   np.float32)
+                    cys = np.zeros((gb, b), np.float32)
+                    for i, r in enumerate(group):
+                        cxs[i, :lens[i]] = r.ctx_x
+                        cys[i, :lens[i]] = r.ctx_y
+                    pre = _prefill_batch(
+                        self.params, self.gcfg, jnp.asarray(cxs),
+                        jnp.asarray(cys),
+                        jnp.asarray(np.pad(lens, (0, gb - len(group)))))
+                    self.stats.prefills += len(group)
+                    for i, r in enumerate(group):
+                        entry = (pre.k[i], pre.v[i], int(lens[i]))
+                        fresh[r.prefix_key] = entry
+                        self._cache_put(r.prefix_key, entry)
+                        if r.prefix_key is None:
+                            entries[id(r)] = entry
+                prefills.append((b, gb, len(group)))
+
+            # gather + pad to the batch buckets, decode once
+            with span("serve.gather"):
+                ks, vs, lens, txs = [], [], [], []
+                for r in reqs:
+                    k, v, mlen = entries.get(id(r)) or fresh[r.prefix_key]
+                    pad_m = ctx_b - k.shape[1]
+                    if pad_m:
+                        widths = ((0, 0), (0, pad_m), (0, 0), (0, 0))
+                        k, v = jnp.pad(k, widths), jnp.pad(v, widths)
+                    ks.append(k)
+                    vs.append(v)
+                    lens.append(mlen)
+                    tx = np.zeros((tgt_b, r.tgt_x.shape[1]), np.float32)
+                    tx[:r.tgt_x.shape[0]] = r.tgt_x
+                    txs.append(tx)
+                pad_rows = batch_b - take
+                if pad_rows:
+                    ks.extend([jnp.zeros_like(ks[0])] * pad_rows)
+                    vs.extend([jnp.zeros_like(vs[0])] * pad_rows)
+                    lens.extend([0] * pad_rows)
+                    txs.extend([np.zeros_like(txs[0])] * pad_rows)
+                pk, pv = jnp.stack(ks), jnp.stack(vs)
+                ctx_len = jnp.asarray(lens, jnp.int32)
+                tgt_x = jnp.asarray(np.stack(txs))
+            with span("serve.decode",
+                      rows=sum(r.tgt_x.shape[0] for r in reqs),
+                      computed=batch_b * tgt_b):
+                preds = _decode_batch(self.params, self.gcfg,
+                                      self.num_options, pk, pv, ctx_len,
+                                      tgt_x)
+            with span("serve.wait"):
+                preds = np.asarray(jax.block_until_ready(preds))
+
+            with span("serve.complete"):
+                finished = self.now()
+                batch_index = len(self.batches)
+                self.batches.append(BatchRecord(
+                    rids=tuple(r.rid for r in reqs), batch_pad=batch_b,
+                    ctx_bucket=ctx_b, tgt_bucket=tgt_b, hits=tuple(hits),
+                    prefills=tuple(prefills)))
+                out = []
+                for i, r in enumerate(reqs):
+                    rows = r.tgt_x.shape[0] // self.num_options
+                    out.append(Completed(
+                        rid=r.rid, pred=preds[i, :rows], cache_hit=hits[i],
+                        arrival=r.arrival, finished=finished,
+                        batch_index=batch_index))
+                    self.stats.completed += 1
+            return out
+
+    def _admit(self):
+        """Pop up to ``max_batch`` live head-of-line requests (dropping
+        those whose deadline has passed), pick the batch's buckets, look
+        up the cache, and group the misses to prefill by their own ctx
+        bucket (a miss key shared within the batch prefills once).
+        Returns (requests, ctx bucket, tgt bucket, batch bucket, hit
+        flags, cached entries by ``id(request)``, misses by bucket), or
+        None when no live request is queued."""
         now = self.now()
         reqs: List[Request] = []
         while self._queue and len(reqs) < self.scfg.max_batch:
@@ -277,15 +383,12 @@ class PreferenceServer:
                 continue
             reqs.append(r)
         if not reqs:
-            return []
-        take = len(reqs)
+            return None
         ctx_b = _bucket_of(max(r.ctx_x.shape[0] for r in reqs),
                            self.scfg.ctx_buckets, "ctx")
         tgt_b = _bucket_of(max(r.tgt_x.shape[0] for r in reqs),
                            self.scfg.tgt_buckets, "tgt")
-        batch_b = _bucket_of(take, self.scfg.batch_buckets, "batch")
-
-        # cache lookups; a miss key shared within the batch prefills once
+        batch_b = _bucket_of(len(reqs), self.scfg.batch_buckets, "batch")
         entries: dict = {}
         hits: List[bool] = []
         misses: List[Request] = []
@@ -303,77 +406,11 @@ class PreferenceServer:
                     misses.append(r)
                     if r.prefix_key is not None:
                         seen_miss_keys.add(r.prefix_key)
-
-        # batched prefill of the misses, grouped by own ctx bucket
         by_bucket: dict[int, List[Request]] = {}
         for r in misses:
             b = _bucket_of(r.ctx_x.shape[0], self.scfg.ctx_buckets, "ctx")
             by_bucket.setdefault(b, []).append(r)
-        fresh: dict = {}
-        for b, group in sorted(by_bucket.items()):
-            gb = _bucket_of(len(group), self.scfg.batch_buckets, "batch")
-            cxs = np.zeros((gb, b, group[0].ctx_x.shape[1]), np.float32)
-            cys = np.zeros((gb, b), np.float32)
-            lens = np.zeros((gb,), np.int32)
-            for i, r in enumerate(group):
-                mlen = r.ctx_x.shape[0]
-                cxs[i, :mlen] = r.ctx_x
-                cys[i, :mlen] = r.ctx_y
-                lens[i] = mlen
-            pre = _prefill_batch(self.params, self.gcfg,
-                                 jnp.asarray(cxs), jnp.asarray(cys),
-                                 jnp.asarray(lens))
-            self.stats.prefills += len(group)
-            for i, r in enumerate(group):
-                entry = (pre.k[i], pre.v[i], int(lens[i]))
-                fresh[r.prefix_key] = entry
-                self._cache_put(r.prefix_key, entry)
-                if r.prefix_key is None:
-                    entries[id(r)] = entry
-        for r in reqs:
-            if id(r) not in entries:
-                entries[id(r)] = fresh[r.prefix_key]
-
-        # gather + pad to the batch buckets, decode once
-        ks, vs, lens, txs = [], [], [], []
-        for r in reqs:
-            k, v, mlen = entries[id(r)]
-            pad_m = ctx_b - k.shape[1]
-            if pad_m:
-                widths = ((0, 0), (0, pad_m), (0, 0), (0, 0))
-                k, v = jnp.pad(k, widths), jnp.pad(v, widths)
-            ks.append(k)
-            vs.append(v)
-            lens.append(mlen)
-            tx = np.zeros((tgt_b, r.tgt_x.shape[1]), np.float32)
-            tx[:r.tgt_x.shape[0]] = r.tgt_x
-            txs.append(tx)
-        pad_rows = batch_b - take
-        if pad_rows:
-            ks.extend([jnp.zeros_like(ks[0])] * pad_rows)
-            vs.extend([jnp.zeros_like(vs[0])] * pad_rows)
-            lens.extend([0] * pad_rows)
-            txs.extend([np.zeros_like(txs[0])] * pad_rows)
-        preds = _decode_batch(
-            self.params, self.gcfg, self.num_options,
-            jnp.stack(ks), jnp.stack(vs),
-            jnp.asarray(lens, jnp.int32), jnp.asarray(np.stack(txs)))
-        preds = np.asarray(jax.block_until_ready(preds))
-
-        finished = self.now()
-        batch_index = len(self.batches)
-        self.batches.append(BatchRecord(
-            rids=tuple(r.rid for r in reqs), batch_pad=batch_b,
-            ctx_bucket=ctx_b, tgt_bucket=tgt_b, hits=tuple(hits)))
-        out = []
-        for i, r in enumerate(reqs):
-            rows = r.tgt_x.shape[0] // self.num_options
-            out.append(Completed(
-                rid=r.rid, pred=preds[i, :rows], cache_hit=hits[i],
-                arrival=r.arrival, finished=finished,
-                batch_index=batch_index))
-            self.stats.completed += 1
-        return out
+        return reqs, ctx_b, tgt_b, batch_b, hits, entries, by_bucket
 
     # -- open-loop trace driver ----------------------------------------
     def run_trace(self, requests: Sequence[Request],
