@@ -206,7 +206,7 @@ def phase_serve(params, gcfg, data, ev):
           f"predict_preferences within atol {SERVE_INT8_ATOL}")
     m = ServeConfig().ctx_buckets[0]
     names = kernel_names(
-        _prefill_batch, server.params, gcfg,
+        _prefill_batch, server.params, gcfg, ServeConfig().ctx_buckets[-1],
         jnp.zeros((1, m, gcfg.d_embed)), jnp.zeros((1, m)),
         jnp.full((1,), m, jnp.int32))
     check("_int8_matmul_kernel" in names,

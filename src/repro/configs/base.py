@@ -465,6 +465,10 @@ class ServeConfig:
     max_queue: int = 128
     # prefix-cache capacity in entries (LRU eviction); 0 disables the
     # cache (every request prefills — the benchmark cold baseline).
+    # Every entry is stored at the largest ctx bucket (zero rows past
+    # its own): 2 x num_layers x ctx_buckets[-1] x d_model x 4 bytes,
+    # 655 KB for the default 4-layer, d_model=128 predictor, so 256 entries
+    # hold at most 168 MB of device memory.
     cache_entries: int = 256
     # quantize the predictor's dense weights to int8 at load time and
     # serve through the fused int8 matmul kernel (DESIGN.md §12)

@@ -151,21 +151,30 @@ class ServeStats:
 # ---------------------------------------------------------------------------
 # jitted batch kernels (params passed positionally: jit caches per shape)
 # ---------------------------------------------------------------------------
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def _prefill_batch(params, cfg: GPOConfig, ctx_x, ctx_y, ctx_len):
-    """(B, M, d), (B, M), (B,) -> stacked GPOPrefix with (B, L, M, nh, hd)
-    K/V."""
+@functools.partial(jax.jit, static_argnames=("cfg", "ctx_pad"))
+def _prefill_batch(params, cfg: GPOConfig, ctx_pad: int, ctx_x, ctx_y,
+                   ctx_len):
+    """(B, M, d), (B, M), (B,) -> (k, v): two tuples of B per-row
+    (L, ctx_pad, nh, hd) arrays. The work runs at the group's own
+    bucket M; rows M..ctx_pad are exact zeros, so every cache entry has
+    one shape whatever its bucket."""
     with jax.named_scope("prefill"):
-        return jax.vmap(
+        pre = jax.vmap(
             lambda cx, cy, cl: gpo_prefill(params, cfg, cx, cy, ctx_len=cl)
         )(ctx_x, ctx_y, ctx_len)
+        widths = ((0, 0), (0, 0), (0, ctx_pad - ctx_x.shape[1]), (0, 0),
+                  (0, 0))
+        k, v = jnp.pad(pre.k, widths), jnp.pad(pre.v, widths)
+        return tuple(k), tuple(v)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "num_options"))
-def _decode_batch(params, cfg: GPOConfig, num_options: int,
-                  pk, pv, ctx_len, tgt_x):
-    """(B, L, M, nh, hd) x2, (B,), (B, T, d) -> (B, T/A, A) normalized
-    preference rows (the ``predict_preferences`` clip-and-normalize)."""
+@functools.partial(jax.jit, static_argnames=("cfg", "num_options", "ctx_b"))
+def _decode_batch(params, cfg: GPOConfig, num_options: int, ctx_b: int,
+                  ks, vs, ctx_len, tgt_x):
+    """B-tuples of cache entries (L, M_max, nh, hd), (B,), (B, T, d) ->
+    (B, T/A, A) normalized preference rows (the ``predict_preferences``
+    clip-and-normalize). The entries are stacked and cut to the batch's
+    context bucket ``ctx_b`` here, inside the one program."""
 
     def one(k, v, cl, tx):
         mu, _ = gpo_decode(params, cfg, GPOPrefix(k=k, v=v), tx, ctx_len=cl)
@@ -173,6 +182,8 @@ def _decode_batch(params, cfg: GPOConfig, num_options: int,
         return scores / scores.sum(axis=-1, keepdims=True)
 
     with jax.named_scope("decode"):
+        pk = jnp.stack(ks)[:, :, :ctx_b]
+        pv = jnp.stack(vs)[:, :, :ctx_b]
         return jax.vmap(one)(pk, pv, ctx_len, tgt_x)
 
 
@@ -190,6 +201,14 @@ class PreferenceServer:
     ``submit`` enqueues (or rejects), ``step`` retires one fused batch,
     ``run_trace`` drives a full arrival trace open-loop and returns the
     completed results with per-request latencies.
+
+    A prefix-cache entry is the K and V of one context, each
+    (num_layers, ctx_buckets[-1], num_heads, head_dim) f32: computed at
+    the context's own bucket and zero-padded to the largest by the
+    prefill program, so it costs 2 x num_layers x ctx_buckets[-1] x
+    d_model x 4 bytes whatever its length (655 KB at 4 layers, d_model
+    128 and a largest bucket of 160). ``_decode_batch`` gathers the
+    batch's entries and cuts them to its context bucket on the device.
     """
 
     def __init__(self, params: PyTree, gpo_cfg: GPOConfig,
@@ -208,9 +227,13 @@ class PreferenceServer:
         self.params = (quantize_gpo_params(params)
                        if serve_cfg.int8_weights else params)
         self._queue: deque[Request] = deque()
-        # prefix_key -> (k (L, Mb, nh, hd), v, ctx_len) at the request's
-        # own ctx bucket Mb
+        # prefix_key -> (k (L, M_max, nh, hd), v, ctx_len): computed at
+        # the request's own ctx bucket, zero-padded to the largest one
         self._cache: OrderedDict[Hashable, tuple] = OrderedDict()
+        # K and V of a partial batch's padding rows
+        self._zero_entry = jnp.zeros(
+            (gpo_cfg.num_layers, serve_cfg.ctx_buckets[-1],
+             gpo_cfg.num_heads, gpo_cfg.head_dim), jnp.float32)
         self.batches: List[BatchRecord] = []
         self.stats = ServeStats()
         self._clock_start = time.perf_counter()
@@ -287,7 +310,6 @@ class PreferenceServer:
             if batch is None:
                 return []
             reqs, ctx_b, tgt_b, batch_b, hits, entries, by_bucket = batch
-            take = len(reqs)
 
             # batched prefill of the misses, grouped by own ctx bucket
             fresh: dict = {}
@@ -303,49 +325,38 @@ class PreferenceServer:
                     for i, r in enumerate(group):
                         cxs[i, :lens[i]] = r.ctx_x
                         cys[i, :lens[i]] = r.ctx_y
-                    pre = _prefill_batch(
-                        self.params, self.gcfg, jnp.asarray(cxs),
-                        jnp.asarray(cys),
+                    pk, pv = _prefill_batch(
+                        self.params, self.gcfg, self.scfg.ctx_buckets[-1],
+                        jnp.asarray(cxs), jnp.asarray(cys),
                         jnp.asarray(np.pad(lens, (0, gb - len(group)))))
                     self.stats.prefills += len(group)
                     for i, r in enumerate(group):
-                        entry = (pre.k[i], pre.v[i], int(lens[i]))
+                        entry = (pk[i], pv[i], int(lens[i]))
                         fresh[r.prefix_key] = entry
                         self._cache_put(r.prefix_key, entry)
                         if r.prefix_key is None:
                             entries[id(r)] = entry
                 prefills.append((b, gb, len(group)))
 
-            # gather + pad to the batch buckets, decode once
+            # gather the entries and pack the targets; the decode
+            # program stacks and cuts the entries to ctx_b itself
             with span("serve.gather"):
-                ks, vs, lens, txs = [], [], [], []
-                for r in reqs:
-                    k, v, mlen = entries.get(id(r)) or fresh[r.prefix_key]
-                    pad_m = ctx_b - k.shape[1]
-                    if pad_m:
-                        widths = ((0, 0), (0, pad_m), (0, 0), (0, 0))
-                        k, v = jnp.pad(k, widths), jnp.pad(v, widths)
-                    ks.append(k)
-                    vs.append(v)
-                    lens.append(mlen)
-                    tx = np.zeros((tgt_b, r.tgt_x.shape[1]), np.float32)
-                    tx[:r.tgt_x.shape[0]] = r.tgt_x
-                    txs.append(tx)
-                pad_rows = batch_b - take
-                if pad_rows:
-                    ks.extend([jnp.zeros_like(ks[0])] * pad_rows)
-                    vs.extend([jnp.zeros_like(vs[0])] * pad_rows)
-                    lens.extend([0] * pad_rows)
-                    txs.extend([np.zeros_like(txs[0])] * pad_rows)
-                pk, pv = jnp.stack(ks), jnp.stack(vs)
-                ctx_len = jnp.asarray(lens, jnp.int32)
-                tgt_x = jnp.asarray(np.stack(txs))
+                ks = [self._zero_entry] * batch_b
+                vs = list(ks)
+                lens = np.zeros(batch_b, np.int32)
+                txs = np.zeros((batch_b, tgt_b, self.gcfg.d_embed),
+                               np.float32)
+                for i, r in enumerate(reqs):
+                    ks[i], vs[i], lens[i] = (entries.get(id(r))
+                                             or fresh[r.prefix_key])
+                    txs[i, :r.tgt_x.shape[0]] = r.tgt_x
+                ctx_len, tgt_x = jax.device_put((lens, txs))
             with span("serve.decode",
                       rows=sum(r.tgt_x.shape[0] for r in reqs),
                       computed=batch_b * tgt_b):
                 preds = _decode_batch(self.params, self.gcfg,
-                                      self.num_options, pk, pv, ctx_len,
-                                      tgt_x)
+                                      self.num_options, ctx_b, tuple(ks),
+                                      tuple(vs), ctx_len, tgt_x)
             with span("serve.wait"):
                 preds = np.asarray(jax.block_until_ready(preds))
 

@@ -2,6 +2,8 @@
 ragged-batch equivalence, cache hit==miss numerics, int8 tolerance,
 deterministic scheduling, admission, and the checkpoint restore contract.
 """
+import contextlib
+
 import numpy as np
 import pytest
 
@@ -39,6 +41,10 @@ from repro.kernels.ref import ref_int8_matmul
 CFG = GPOConfig(d_embed=16, d_model=32, num_layers=2, num_heads=4, d_ff=64)
 SCFG = ServeConfig(max_batch=4, batch_buckets=(1, 2, 4),
                    ctx_buckets=(20, 40), tgt_buckets=(10, 20),
+                   cache_entries=16)
+# three context buckets: entries own 40 or 80 rows, stored at 160
+WIDE = ServeConfig(max_batch=4, batch_buckets=(1, 2, 4),
+                   ctx_buckets=(40, 80, 160), tgt_buckets=(10, 20),
                    cache_entries=16)
 
 
@@ -194,6 +200,72 @@ def test_ragged_batch_equals_one_at_a_time():
         assert batched[r.rid].shape == (r.tgt_x.shape[0] // 5, 5)
 
 
+@contextlib.contextmanager
+def _backend_compiles():
+    """Collect the backend compilations that happen inside the block."""
+    seen = []
+
+    def on(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            seen.append(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(on)
+    try:
+        yield seen
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on)
+
+
+def test_mixed_bucket_hits_compile_nothing_new():
+    """Once (4, 40, 20) and (4, 80, 20) have been served, a batch of hits
+    whose entries own buckets 40 and 80, in any order, reuses the
+    compiled programs, and each row equals the request served alone."""
+    params = _params(0, scale=2.0)
+    own40 = [_request(i, 60 + i, m=m, t=t, prefix_key=("a", i))
+             for i, (m, t) in enumerate([(24, 20), (36, 10), (30, 15),
+                                         (40, 20)])]
+    own80 = [_request(4 + i, 70 + i, m=m, t=t, prefix_key=("b", i))
+             for i, (m, t) in enumerate([(60, 15), (45, 20), (80, 10),
+                                         (52, 20)])]
+    srv = PreferenceServer(params, CFG, WIDE, num_options=5)
+    for group, ctx_b in ((own40, 40), (own80, 80)):
+        for r in group:
+            srv.submit(r)
+        srv.step()
+        assert (srv.batches[-1].batch_pad, srv.batches[-1].ctx_bucket,
+                srv.batches[-1].tgt_bucket) == (4, ctx_b, 20)
+
+    def again(r, rid):
+        return Request(rid=rid, ctx_x=r.ctx_x, ctx_y=r.ctx_y,
+                       tgt_x=r.tgt_x, prefix_key=r.prefix_key)
+
+    orders = ([own40[0], own80[0], own40[1], own80[1]],
+              [own80[2], own40[2], own80[3], own40[3]],
+              [own80[1], own80[0], own40[1], own40[0]])
+    served = {}
+    with _backend_compiles() as compiles:
+        for n, order in enumerate(orders):
+            for r in order:
+                srv.submit(again(r, 100 * (n + 1) + r.rid))
+            for c in srv.step():
+                served.setdefault(c.rid % 100, []).append(c.pred)
+            rec = srv.batches[-1]
+            assert all(rec.hits)
+            assert (rec.batch_pad, rec.ctx_bucket, rec.tgt_bucket) == (4, 80,
+                                                                       20)
+    assert compiles == []
+    solo_cfg = ServeConfig(max_batch=1, batch_buckets=(1,),
+                           ctx_buckets=WIDE.ctx_buckets,
+                           tgt_buckets=WIDE.tgt_buckets, cache_entries=0)
+    for r in own40 + own80:
+        solo = PreferenceServer(params, CFG, solo_cfg, num_options=5)
+        solo.submit(r)
+        alone = solo.step()[0].pred
+        for pred in served[r.rid]:
+            np.testing.assert_allclose(alone, pred, rtol=1e-5, atol=1e-6)
+    assert sorted(served) == list(range(8))
+
+
 def test_engine_matches_predict_preferences():
     params = _params(0, scale=2.0)
     r = _request(0, 20)
@@ -221,6 +293,34 @@ def test_prefix_cache_hit_bit_equal_to_miss():
     assert srv.stats.cache_hits == 1 and srv.stats.cache_misses == 1
     assert srv.stats.prefills == 1  # the hit skipped prefill entirely
     assert np.array_equal(cold.pred, warm.pred)
+
+
+def test_prefix_cache_hit_bit_equal_to_miss_at_a_larger_bucket():
+    """An entry prefilled at its own bucket of 40 is stored with exact
+    zeros from row 40 to the largest bucket; a hit on it in a batch at
+    bucket 80 is bit-equal to its cold miss in the same batch shape."""
+    params = _params(0, scale=2.0)
+    srv = PreferenceServer(params, CFG, WIDE, num_options=5)
+    short = _request(0, 31, m=30, t=10, prefix_key="own40")
+    long = _request(1, 32, m=60, t=10, prefix_key="own80")
+    for r in (short, long):
+        srv.submit(r)
+    cold = srv.step()
+    k, v, ctx_len = srv._cache["own40"]
+    assert ctx_len == 30
+    assert k.shape[1] == v.shape[1] == WIDE.ctx_buckets[-1]
+    for a in (np.asarray(k), np.asarray(v)):
+        assert np.all(a[:, 40:] == 0.0) and np.any(a[:, :40] != 0.0)
+    for r in (short, long):
+        srv.submit(Request(rid=r.rid + 10, ctx_x=r.ctx_x, ctx_y=r.ctx_y,
+                           tgt_x=r.tgt_x, prefix_key=r.prefix_key))
+    warm = srv.step()
+    first, second = srv.batches
+    assert first.hits == (False, False) and second.hits == (True, True)
+    assert first.ctx_bucket == second.ctx_bucket == 80
+    assert srv.stats.prefills == 2
+    for c, w in zip(cold, warm):
+        assert np.array_equal(c.pred, w.pred)
 
 
 def test_prefix_cache_hit_independent_of_batch_composition():
