@@ -28,6 +28,7 @@ from repro.configs.base import (  # noqa: F401
 from repro.configs import (  # noqa: F401,E402
     gemma2_27b,
     gemma3_27b,
+    granite_4_0_h_small,
     granite_moe_3b_a800m,
     grok_1_314b,
     llava_next_34b,

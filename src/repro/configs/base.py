@@ -49,6 +49,10 @@ class ModelConfig:
     attn_logit_softcap: Optional[float] = None  # gemma2-style soft capping
     final_logit_softcap: Optional[float] = None
     rope_theta: float = 10_000.0
+    # False = no positional encoding in attention (NoPE: granite-4.0-h)
+    use_rope: bool = True
+    # attention score multiplier; None = 1 / sqrt(head_dim)
+    attn_scale: Optional[float] = None
     rope_theta_global: Optional[float] = None  # gemma3: different theta for global
     # sliding-window pattern, cycled over attention layers. -1 == global.
     window_pattern: Tuple[int, ...] = (GLOBAL,)
@@ -58,7 +62,14 @@ class ModelConfig:
     num_experts: int = 0  # 0 => dense MLP
     experts_per_token: int = 0
     router_aux_coef: float = 0.01
-    moe_capacity_factor: float = 1.25  # tokens dropped beyond capacity
+    # tokens dropped beyond capacity; 0 = dropless grouped experts
+    moe_capacity_factor: float = 1.25
+    # experts held on this chip (the first ``experts_held`` of
+    # ``num_experts``; the router still scores all of them); 0 = all.
+    # Needs the dropless layer.
+    experts_held: int = 0
+    # width of one always-on shared expert beside the routed ones; 0 = none
+    shared_expert_ff: int = 0
 
     # SSM (Mamba2 / SSD)
     ssm_state_size: int = 0
@@ -70,7 +81,10 @@ class ModelConfig:
     # layer pattern: cycled to num_layers. ("attn",) pure transformer,
     # ("mamba",) pure SSM. Hybrid (zamba2) uses block_pattern plus
     # shared_attn_every (a single *shared-weight* attention block applied
-    # after every k trunk layers, as in Zamba2).
+    # after every k trunk layers, as in Zamba2). A pattern that mixes
+    # "mamba" and "attn" (granite-4.0-h) gives every layer its own
+    # weights and its own MLP/MoE after the mixer; num_layers is then a
+    # whole number of periods.
     block_pattern: Tuple[str, ...] = (ATTN,)
     shared_attn_every: int = 0  # 0 => no shared block
 
@@ -100,6 +114,11 @@ class ModelConfig:
     norm_eps: float = 1e-6
     tie_embeddings: bool = True
     scale_embeddings: bool = False  # gemma: embed * sqrt(d_model)
+    # granite: embed * embedding_multiplier, each residual branch *
+    # residual_multiplier, logits / logits_scaling
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     use_post_norm: bool = False  # gemma2/3 sandwich norm
 
     # ---------------------------------------------------------------
@@ -114,6 +133,16 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def is_mixed(self) -> bool:
+        """Mamba and attention layers, each with its own weights."""
+        return (not self.shared_attn_every
+                and {ATTN, MAMBA} <= set(self.block_pattern))
+
+    @property
+    def num_experts_held(self) -> int:
+        return self.experts_held or self.num_experts
 
     def layer_kinds(self) -> Tuple[str, ...]:
         """Per-layer block kind, block_pattern cycled to num_layers."""
@@ -135,6 +164,11 @@ class ModelConfig:
         assert self.num_heads % max(self.num_kv_heads, 1) == 0, self.name
         if self.is_moe:
             assert 0 < self.experts_per_token <= self.num_experts, self.name
+            assert 0 <= self.experts_held <= self.num_experts, self.name
+            assert not self.experts_held or not self.moe_capacity_factor, (
+                f"{self.name}: experts_held needs the dropless layer")
+        if self.is_mixed:
+            assert self.num_layers % len(self.block_pattern) == 0, self.name
         kinds = set(self.layer_kinds())
         if MAMBA in kinds:
             assert self.ssm_state_size > 0, self.name
@@ -473,11 +507,19 @@ class ServeConfig:
     # quantize the predictor's dense weights to int8 at load time and
     # serve through the fused int8 matmul kernel (DESIGN.md §12)
     int8_weights: bool = False
+    # requests whose targets are token texts: each text pads to the
+    # smallest length bucket that fits (tokens), and a step's texts of
+    # one length bucket run through the backbone in groups padded to a
+    # row bucket, so the embed programs are len x len of these
+    text_buckets: Tuple[int, ...] = (128, 256, 512)
+    text_row_buckets: Tuple[int, ...] = (12, 20, 28)
 
     def validate(self) -> None:
         for name, buckets in (("batch_buckets", self.batch_buckets),
                               ("ctx_buckets", self.ctx_buckets),
-                              ("tgt_buckets", self.tgt_buckets)):
+                              ("tgt_buckets", self.tgt_buckets),
+                              ("text_buckets", self.text_buckets),
+                              ("text_row_buckets", self.text_row_buckets)):
             if not buckets or list(buckets) != sorted(set(buckets)):
                 raise ValueError(
                     f"{name} must be non-empty strictly ascending, got "
@@ -749,7 +791,9 @@ def get_arch(name: str) -> ModelConfig:
 
 def smoke_variant(cfg: ModelConfig) -> ModelConfig:
     """Reduced variant of the same family: 2 layers, d_model<=512,
-    <=4 experts — runnable on CPU in a test."""
+    <=4 experts — runnable on CPU in a test. A mixed Mamba/attention
+    pattern keeps one layer of each kind, in the order they first
+    appear."""
     updates = dict(
         num_layers=2,
         d_model=min(cfg.d_model, 256),
@@ -763,7 +807,13 @@ def smoke_variant(cfg: ModelConfig) -> ModelConfig:
     )
     if cfg.is_moe:
         updates.update(num_experts=4, experts_per_token=min(2, cfg.experts_per_token),
-                       moe_capacity_factor=4.0)  # drop-free for exact tests
+                       experts_held=0)
+        if cfg.moe_capacity_factor:
+            updates.update(moe_capacity_factor=4.0)  # drop-free for exact tests
+    if cfg.shared_expert_ff:
+        updates.update(shared_expert_ff=min(cfg.shared_expert_ff, 512))
+    if cfg.is_mixed:
+        updates.update(block_pattern=tuple(dict.fromkeys(cfg.block_pattern)))
     if cfg.ssm_state_size:
         updates.update(ssm_state_size=min(cfg.ssm_state_size, 32), ssm_head_dim=32,
                        ssm_chunk=16)

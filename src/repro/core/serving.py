@@ -32,6 +32,13 @@ query load. This module turns the single-tenant, synchronous
   weights as ``QuantizedLinear`` leaves at load time (per-output-channel
   symmetric scales, the §10 contract) and ``core/gpo.py::_mm`` routes
   them through the fused int8 matmul kernel.
+* **Token-text targets** — with an ``embedder`` (a frozen backbone's
+  config and params), a request may carry its targets as token texts
+  (``tgt_tokens``) in place of embeddings. ``step`` embeds the batch's
+  texts on the device, grouped by length bucket (one program per length
+  and row bucket), as the masked mean pool of the backbone's final-normed
+  hidden states, unit-normed, and places them into the decode input
+  there: no embedding comes back to the host before the decode.
 
 Everything timing-related is measurement only: scheduling decisions
 depend exclusively on queue order, so a fixed arrival trace yields a
@@ -49,9 +56,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import GPOConfig, ServeConfig
+from repro.configs.base import GPOConfig, ModelConfig, ServeConfig
 from repro.core.gpo import GPOLayer, GPOPrefix, gpo_decode, gpo_prefill
+from repro.data.embeddings import masked_mean, unit_norm
 from repro.kernels import quantize_linear
+from repro.models.transformer import hidden_states
 from repro.utils.spans import span
 
 PyTree = Any
@@ -87,7 +96,10 @@ def quantize_gpo_params(params: PyTree) -> PyTree:
 @dataclass
 class Request:
     """One preference query: predict a group's answer distributions for
-    ``tgt_x`` given the (ctx_x, ctx_y) in-context examples.
+    ``tgt_x`` given the (ctx_x, ctx_y) in-context examples. In place of
+    ``tgt_x`` the targets may be token texts, one per target point:
+    ``tgt_tokens`` (t*A, max_len) int32, padded on the right, with their
+    lengths ``tgt_lens`` (t*A,); the server's embedder embeds them.
     ``prefix_key`` identifies the shared context for prefix caching —
     two requests with the same key MUST carry identical (ctx_x, ctx_y);
     None disables caching for this request. ``arrival`` is seconds on
@@ -101,11 +113,20 @@ class Request:
     rid: int
     ctx_x: np.ndarray  # (m*A, d_embed)
     ctx_y: np.ndarray  # (m*A,)
-    tgt_x: np.ndarray  # (t*A, d_embed)
+    tgt_x: Optional[np.ndarray] = None  # (t*A, d_embed)
     prefix_key: Optional[Hashable] = None
     arrival: float = 0.0
     deadline: Optional[float] = None  # absolute engine-clock seconds
     meta: Optional[dict] = None  # caller-owned (e.g. group/question ids)
+    tgt_tokens: Optional[np.ndarray] = None  # (t*A, max_len) int32
+    tgt_lens: Optional[np.ndarray] = None  # (t*A,)
+
+    @property
+    def num_targets(self) -> int:
+        """Target points (t*A), embeddings or texts."""
+        if self.tgt_x is not None:
+            return self.tgt_x.shape[0]
+        return len(self.tgt_lens)
 
 
 @dataclass
@@ -187,6 +208,30 @@ def _decode_batch(params, cfg: GPOConfig, num_options: int, ctx_b: int,
         return jax.vmap(one)(pk, pv, ctx_len, tgt_x)
 
 
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def _embed_texts(params, cfg: ModelConfig, tokens, lens, rows_total):
+    """(R, L) token texts padded on the right, (R,) lengths (0 for a
+    padding row) -> (unit embeddings (R, d), pooled embeddings before
+    the norm (R, d) f32, ``rows_total`` plus the (token, expert) pairs
+    the backbone's dropless MoE handed to its grouped products)."""
+    with jax.named_scope("backbone"):
+        hidden, _, rows = hidden_states(params, cfg, tokens=tokens)
+    mask = (jnp.arange(tokens.shape[1])[None, :] < lens[:, None])
+    pooled = masked_mean(hidden.astype(jnp.float32),
+                         mask.astype(jnp.float32))
+    return unit_norm(pooled), pooled, rows_total + rows
+
+
+@jax.jit
+def _place_texts(tgt_x, emb, slots):
+    """Write the rows of ``emb`` into the flat (batch * tgt) slots of
+    ``tgt_x`` (B, T, d); slots past the end (padding rows) are dropped."""
+    b, t, d = tgt_x.shape
+    flat = tgt_x.reshape(b * t, d).at[slots].set(
+        emb.astype(tgt_x.dtype), mode="drop")
+    return flat.reshape(b, t, d)
+
+
 def _bucket_of(n: int, buckets: Sequence[int], what: str) -> int:
     for b in buckets:
         if n <= b:
@@ -213,8 +258,14 @@ class PreferenceServer:
 
     def __init__(self, params: PyTree, gpo_cfg: GPOConfig,
                  serve_cfg: ServeConfig = ServeConfig(), *,
-                 num_options: int):
+                 num_options: int,
+                 embedder: Optional[Tuple[ModelConfig, PyTree]] = None):
         serve_cfg.validate()
+        if embedder is not None and embedder[0].d_model != gpo_cfg.d_embed:
+            raise ValueError(
+                f"the embedder's width {embedder[0].d_model} is not the "
+                f"predictor's d_embed {gpo_cfg.d_embed}")
+        self.embedder = embedder
         for b in serve_cfg.tgt_buckets:
             if b % num_options:
                 raise ValueError(
@@ -236,6 +287,9 @@ class PreferenceServer:
              gpo_cfg.num_heads, gpo_cfg.head_dim), jnp.float32)
         self.batches: List[BatchRecord] = []
         self.stats = ServeStats()
+        # (token, expert) pairs the embedder's grouped expert products
+        # were handed, summed on the device
+        self.expert_rows = jnp.zeros((), jnp.int32)
         self._clock_start = time.perf_counter()
 
     # -- clock ----------------------------------------------------------
@@ -248,12 +302,19 @@ class PreferenceServer:
         self._queue.clear()
         self.batches = []
         self.stats = ServeStats()
+        self.expert_rows = jnp.zeros((), jnp.int32)
         if clear_cache:
             self._cache.clear()
         self._clock_start = time.perf_counter()
 
     # -- admission ------------------------------------------------------
     def submit(self, req: Request) -> bool:
+        if (req.tgt_x is None) == (req.tgt_tokens is None):
+            raise ValueError("a request carries tgt_x or tgt_tokens, "
+                             "exactly one of them")
+        if req.tgt_tokens is not None and self.embedder is None:
+            raise ValueError("token texts need a server built with an "
+                             "embedder")
         self.stats.submitted += 1
         if self.scfg.max_queue and len(self._queue) >= self.scfg.max_queue:
             self.stats.rejected += 1
@@ -338,6 +399,14 @@ class PreferenceServer:
                             entries[id(r)] = entry
                 prefills.append((b, gb, len(group)))
 
+            # embed the token-text targets (dispatched before the host
+            # packs the rest, so the device starts on them first)
+            texts = [(i * tgt_b + j, r.tgt_tokens[j], n)
+                     for i, r in enumerate(reqs) if r.tgt_tokens is not None
+                     for j, n in enumerate(r.tgt_lens)]
+            placed = [(unit, slots) for unit, _, slots in self._embed_groups(
+                texts, batch_b * tgt_b)]
+
             # gather the entries and pack the targets; the decode
             # program stacks and cuts the entries to ctx_b itself
             with span("serve.gather"):
@@ -349,10 +418,17 @@ class PreferenceServer:
                 for i, r in enumerate(reqs):
                     ks[i], vs[i], lens[i] = (entries.get(id(r))
                                              or fresh[r.prefix_key])
-                    txs[i, :r.tgt_x.shape[0]] = r.tgt_x
-                ctx_len, tgt_x = jax.device_put((lens, txs))
+                    if r.tgt_x is not None:
+                        txs[i, :r.tgt_x.shape[0]] = r.tgt_x
+                if placed and all(r.tgt_x is None for r in reqs):
+                    ctx_len = jax.device_put(lens)
+                    tgt_x = jnp.zeros(txs.shape, txs.dtype)
+                else:
+                    ctx_len, tgt_x = jax.device_put((lens, txs))
+                for unit, slots in placed:
+                    tgt_x = _place_texts(tgt_x, unit, slots)
             with span("serve.decode",
-                      rows=sum(r.tgt_x.shape[0] for r in reqs),
+                      rows=sum(r.num_targets for r in reqs),
                       computed=batch_b * tgt_b):
                 preds = _decode_batch(self.params, self.gcfg,
                                       self.num_options, ctx_b, tuple(ks),
@@ -369,13 +445,65 @@ class PreferenceServer:
                     prefills=tuple(prefills)))
                 out = []
                 for i, r in enumerate(reqs):
-                    rows = r.tgt_x.shape[0] // self.num_options
+                    rows = r.num_targets // self.num_options
                     out.append(Completed(
                         rid=r.rid, pred=preds[i, :rows], cache_hit=hits[i],
                         arrival=r.arrival, finished=finished,
                         batch_index=batch_index))
                     self.stats.completed += 1
             return out
+
+    def _embed_groups(self, texts, n_slots: int):
+        """Run ``texts`` (slot, tokens, length) through the embedder: the
+        texts of one length bucket (``text_buckets``) together, at most
+        ``text_row_buckets[-1]`` a call, padded to a row bucket. Yields
+        (unit embeddings, pooled embeddings before the norm, slots) per
+        call, all on the device; padding rows get slot ``n_slots``.
+        Each call is a ``serve.embed`` span counting its ``texts``, real
+        ``tokens`` and ``computed`` (padded) tokens."""
+        if not texts:
+            return
+        if self.embedder is None:
+            raise ValueError("token texts need a server built with an "
+                             "embedder")
+        cfg, params = self.embedder
+        sc = self.scfg
+        by_len: dict = {}
+        for item in texts:
+            by_len.setdefault(_bucket_of(item[2], sc.text_buckets, "text"),
+                              []).append(item)
+        most = sc.text_row_buckets[-1]
+        for length, items in sorted(by_len.items()):
+            for start in range(0, len(items), most):
+                chunk = items[start:start + most]
+                rb = _bucket_of(len(chunk), sc.text_row_buckets, "text_row")
+                with span("serve.embed", texts=len(chunk),
+                          tokens=sum(n for _, _, n in chunk),
+                          computed=rb * length):
+                    toks = np.zeros((rb, length), np.int32)
+                    lens = np.zeros(rb, np.int32)
+                    slots = np.full(rb, n_slots, np.int32)
+                    for k, (slot, tok, n) in enumerate(chunk):
+                        toks[k, :n], lens[k], slots[k] = tok[:n], n, slot
+                    toks, lens, slots = jax.device_put((toks, lens, slots))
+                    unit, pooled, self.expert_rows = _embed_texts(
+                        params, cfg, toks, lens, self.expert_rows)
+                yield unit, pooled, slots
+
+    def embed(self, tokens: Sequence[np.ndarray]):
+        """Embed token texts with the programs ``step`` uses: returns
+        (unit embeddings, pooled embeddings before the norm), each (n, d)
+        float32 on the host, in the order given."""
+        texts = [(i, t, len(t)) for i, t in enumerate(tokens)]
+        d = self.gcfg.d_embed
+        unit = np.zeros((len(texts), d), np.float32)
+        pooled = np.zeros((len(texts), d), np.float32)
+        for u, p, slots in self._embed_groups(texts, len(texts)):
+            real = np.asarray(slots) < len(texts)
+            idx = np.asarray(slots)[real]
+            unit[idx] = np.asarray(u)[real]
+            pooled[idx] = np.asarray(p)[real]
+        return unit, pooled
 
     def _admit(self):
         """Pop up to ``max_batch`` live head-of-line requests (dropping
@@ -397,7 +525,7 @@ class PreferenceServer:
             return None
         ctx_b = _bucket_of(max(r.ctx_x.shape[0] for r in reqs),
                            self.scfg.ctx_buckets, "ctx")
-        tgt_b = _bucket_of(max(r.tgt_x.shape[0] for r in reqs),
+        tgt_b = _bucket_of(max(r.num_targets for r in reqs),
                            self.scfg.tgt_buckets, "tgt")
         batch_b = _bucket_of(len(reqs), self.scfg.batch_buckets, "batch")
         entries: dict = {}

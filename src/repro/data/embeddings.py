@@ -10,6 +10,9 @@ once per group before training (§4.3). Offline we provide:
   hidden state) so the full pipeline (backbone -> GPO -> FedAvg) is
   exercised end-to-end with real compute in examples/tests on reduced
   configs, and on TPU with the full assigned architectures.
+* ``masked_mean`` and ``unit_norm`` — the pool and the normalisation
+  both that embedder and the serving engine's text path
+  (``core/serving.py``) apply to a backbone's final hidden states.
 """
 from __future__ import annotations
 
@@ -44,6 +47,17 @@ class StubEmbedder:
         return jnp.stack([self.embed_text(t) for t in texts])
 
 
+def masked_mean(hidden: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
+    """(B, S, d) hidden states, (B, S) 0/1 mask -> (B, d) mean over the
+    masked positions (0 for a row with none)."""
+    denom = jnp.maximum(mask.sum(axis=1, keepdims=True), 1)
+    return (hidden * mask[..., None]).sum(axis=1) / denom
+
+
+def unit_norm(x: jnp.ndarray) -> jnp.ndarray:
+    return x / (jnp.linalg.norm(x, axis=-1, keepdims=True) + 1e-6)
+
+
 class BackboneEmbedder:
     """Embed token sequences with a frozen model-zoo backbone.
 
@@ -62,11 +76,10 @@ class BackboneEmbedder:
 
     def _embed(self, tokens: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
         hidden = self.apply_fn(self.params, tokens)  # (B, S, d_model)
-        denom = jnp.maximum(mask.sum(axis=1, keepdims=True), 1)
-        pooled = (hidden * mask[..., None]).sum(axis=1) / denom
+        pooled = masked_mean(hidden, mask)
         if self.proj is not None:
             pooled = pooled @ self.proj
-        return pooled / (jnp.linalg.norm(pooled, axis=-1, keepdims=True) + 1e-6)
+        return unit_norm(pooled)
 
     def embed_tokens(self, tokens: jnp.ndarray,
                      mask: jnp.ndarray | None = None) -> jnp.ndarray:

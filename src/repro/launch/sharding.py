@@ -79,8 +79,9 @@ def param_spec(path: str, shape: tuple, cfg: ModelConfig, mesh, *,
         return tail_matmul("model", None)
     if "router" in path:
         return tail_matmul(d_axis, None)
+    routed = "['moe']" in path  # not a dense MLP or the shared expert
     if re.search(r"w_gate|w_up", path):
-        if cfg.is_moe and nd >= 3:
+        if routed and nd >= 3:
             # (L, E, d, ff): expert-parallel when E divides model axis
             e_dim = nd - 3
             if shape[e_dim] % _axis_size(mesh, "model") == 0:
@@ -88,7 +89,7 @@ def param_spec(path: str, shape: tuple, cfg: ModelConfig, mesh, *,
             return tail_matmul(d_axis, "model")
         return tail_matmul(d_axis, "model")
     if "w_down" in path:
-        if cfg.is_moe and nd >= 3:
+        if routed and nd >= 3:
             e_dim = nd - 3
             if shape[e_dim] % _axis_size(mesh, "model") == 0:
                 return _guarded(mesh, shape, {e_dim: "model", last: d_axis})

@@ -81,9 +81,18 @@ def _project_qkv(p: AttnParams, x, num_heads, num_kv, head_dim, eps):
     return q, k, v
 
 
-def gqa_scores_softmax(q, k, v, mask, logit_cap):
+def _rope_qk(q, k, positions, theta, cfg):
+    """RoPE on q and k; nothing where the config has no positional
+    encoding (NoPE)."""
+    if not cfg.use_rope:
+        return q, k
+    return rope(q, positions, theta), rope(k, positions, theta)
+
+
+def gqa_scores_softmax(q, k, v, mask, logit_cap, scale=None):
     """q (b,sq,H,hd), k/v (b,sk,KV,hd), mask (b,1 or KV*G? , sq, sk) bool.
 
+    Scores are multiplied by ``scale`` (None: 1 / sqrt(hd)).
     Returns (b, sq, H, hd). Softmax in f32.
     """
     b, sq, h, hd = q.shape
@@ -104,7 +113,7 @@ def gqa_scores_softmax(q, k, v, mask, logit_cap):
         scores = jnp.einsum("bqhd,bshd->bhqs", q, k).astype(jnp.float32)
         scores = shard_act(scores, ("batch", "heads", None, None) if h_ok
                            else ("batch", None, "seq_q", None))
-        scores = scores / jnp.sqrt(jnp.asarray(hd, jnp.float32))
+        scores = _scaled(scores, hd, scale)
         if logit_cap is not None:
             scores = softcap(scores, logit_cap)
         scores = jnp.where(mask[:, None, :, :], scores, NEG_INF)
@@ -119,7 +128,7 @@ def gqa_scores_softmax(q, k, v, mask, logit_cap):
     scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, k).astype(jnp.float32)
     scores = shard_act(scores, ("batch", "kv_heads", None,
                                 "seq_q" if q_sharded else None, None))
-    scores = scores / jnp.sqrt(jnp.asarray(hd, jnp.float32))
+    scores = _scaled(scores, hd, scale)
     if logit_cap is not None:
         scores = softcap(scores, logit_cap)
     scores = jnp.where(mask[:, None, None, :, :], scores, NEG_INF)
@@ -127,6 +136,12 @@ def gqa_scores_softmax(q, k, v, mask, logit_cap):
     out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v)
     out = out.reshape(b, sq, h, hd)
     return shard_act(out, ("batch", "seq", "heads", "hd"))
+
+
+def _scaled(scores, hd, scale):
+    if scale is None:
+        return scores / jnp.sqrt(jnp.asarray(hd, jnp.float32))
+    return scores * jnp.asarray(scale, jnp.float32)
 
 
 def make_causal_window_mask(q_pos, k_pos, window, k_valid=None):
@@ -147,7 +162,7 @@ CHUNK_THRESHOLD = 8192
 Q_CHUNK = 1024
 
 
-def _chunked_gqa(q, k, v, window, logit_cap, q_chunk: int):
+def _chunked_gqa(q, k, v, window, logit_cap, q_chunk: int, scale=None):
     """Causal/windowed attention, scanning q in chunks of ``q_chunk``.
 
     q (b,s,h,hd) and k/v (b,s,kv,hd) are already roped. Peak score memory
@@ -165,7 +180,7 @@ def _chunked_gqa(q, k, v, window, logit_cap, q_chunk: int):
         q_pos = (i * q_chunk
                  + jnp.arange(q_chunk, dtype=jnp.int32))[None, :].repeat(b, 0)
         mask = make_causal_window_mask(q_pos, k_pos, window)
-        out = gqa_scores_softmax(qblk, k, v, mask, logit_cap)
+        out = gqa_scores_softmax(qblk, k, v, mask, logit_cap, scale)
         return None, out
 
     _, outs = jax.lax.scan(body, None, (qc, jnp.arange(nq)))
@@ -182,13 +197,14 @@ def attend_full(p: AttnParams, x, cfg, *, window, theta, positions=None):
         positions = jnp.arange(s, dtype=jnp.int32)[None, :].repeat(b, 0)
     q, k, v = _project_qkv(p, x, cfg.num_heads, cfg.num_kv_heads,
                            cfg.head_dim, cfg.norm_eps)
-    q = rope(q, positions, theta)
-    k = rope(k, positions, theta)
+    q, k = _rope_qk(q, k, positions, theta, cfg)
     if s >= CHUNK_THRESHOLD and s % Q_CHUNK == 0:
-        out = _chunked_gqa(q, k, v, window, cfg.attn_logit_softcap, Q_CHUNK)
+        out = _chunked_gqa(q, k, v, window, cfg.attn_logit_softcap, Q_CHUNK,
+                           cfg.attn_scale)
     else:
         mask = make_causal_window_mask(positions, positions, window)
-        out = gqa_scores_softmax(q, k, v, mask, cfg.attn_logit_softcap)
+        out = gqa_scores_softmax(q, k, v, mask, cfg.attn_logit_softcap,
+                                 cfg.attn_scale)
     return jnp.einsum("bse,ed->bsd", out.reshape(b, s, -1), p.wo)
 
 
@@ -201,13 +217,14 @@ def prefill(p: AttnParams, x, cfg, *, window, theta, cache_len):
     positions = jnp.arange(s, dtype=jnp.int32)[None, :].repeat(b, 0)
     q, k, v = _project_qkv(p, x, cfg.num_heads, cfg.num_kv_heads,
                            cfg.head_dim, cfg.norm_eps)
-    q = rope(q, positions, theta)
-    k = rope(k, positions, theta)
+    q, k = _rope_qk(q, k, positions, theta, cfg)
     if s >= CHUNK_THRESHOLD and s % Q_CHUNK == 0:
-        out = _chunked_gqa(q, k, v, window, cfg.attn_logit_softcap, Q_CHUNK)
+        out = _chunked_gqa(q, k, v, window, cfg.attn_logit_softcap, Q_CHUNK,
+                           cfg.attn_scale)
     else:
         mask = make_causal_window_mask(positions, positions, window)
-        out = gqa_scores_softmax(q, k, v, mask, cfg.attn_logit_softcap)
+        out = gqa_scores_softmax(q, k, v, mask, cfg.attn_logit_softcap,
+                                 cfg.attn_scale)
     out = jnp.einsum("bse,ed->bsd", out.reshape(b, s, -1), p.wo)
     pad = cache_len - s
     if pad > 0:
@@ -228,14 +245,14 @@ def decode_step(p: AttnParams, x, k_cache, v_cache, cache_pos, cfg, *,
     pos = jnp.full((b, 1), cache_pos, jnp.int32)
     q, k, v = _project_qkv(p, x, cfg.num_heads, cfg.num_kv_heads,
                            cfg.head_dim, cfg.norm_eps)
-    q = rope(q, pos, theta)
-    k = rope(k, pos, theta)
+    q, k = _rope_qk(q, k, pos, theta, cfg)
     k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, k, cache_pos, axis=1)
     v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, v, cache_pos, axis=1)
     k_pos = jnp.arange(s_max, dtype=jnp.int32)[None, :].repeat(b, 0)
     k_valid = k_pos <= cache_pos  # includes the token just written
     mask = make_causal_window_mask(pos, k_pos, window, k_valid=k_valid)
-    out = gqa_scores_softmax(q, k_cache, v_cache, mask, cfg.attn_logit_softcap)
+    out = gqa_scores_softmax(q, k_cache, v_cache, mask, cfg.attn_logit_softcap,
+                             cfg.attn_scale)
     out = jnp.einsum("bse,ed->bsd", out.reshape(b, 1, -1), p.wo)
     return out, k_cache, v_cache
 
@@ -258,8 +275,7 @@ def ring_decode_step(p: AttnParams, x, k_cache, v_cache, cache_pos, cfg, *,
     pos = jnp.full((b, 1), cache_pos, jnp.int32)
     q, k, v = _project_qkv(p, x, cfg.num_heads, cfg.num_kv_heads,
                            cfg.head_dim, cfg.norm_eps)
-    q = rope(q, pos, theta)
-    k = rope(k, pos, theta)
+    q, k = _rope_qk(q, k, pos, theta, cfg)
     slot = jnp.asarray(cache_pos, jnp.int32) % w
     k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, k, slot, axis=1)
     v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, v, slot, axis=1)
@@ -269,7 +285,7 @@ def ring_decode_step(p: AttnParams, x, k_cache, v_cache, cache_pos, cfg, *,
                          cache_pos - (slot + w - s))
     mask = (true_pos >= 0)[None, None, :].repeat(b, 0)  # (b, 1, w)
     out = gqa_scores_softmax(q, k_cache, v_cache, mask,
-                             cfg.attn_logit_softcap)
+                             cfg.attn_logit_softcap, cfg.attn_scale)
     out = jnp.einsum("bse,ed->bsd", out.reshape(b, 1, -1), p.wo)
     return out, k_cache, v_cache
 

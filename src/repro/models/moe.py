@@ -16,6 +16,11 @@ Design notes (TPU adaptation, see DESIGN.md §4):
 * Tokens overflowing expert capacity C = ceil(T*k/E) * capacity_factor are
   dropped (standard dropping MoE); the router aux loss keeps load balanced
   so drops are rare.
+* ``moe_dropless`` drops nothing: the (token, expert) pairs are sorted by
+  expert and run through grouped products (``jax.lax.ragged_dot``) over
+  the experts this chip holds. The router scores every expert; the pairs
+  of experts held elsewhere add nothing here (expert parallelism without
+  its exchange, the chip's share of the layer).
 """
 from __future__ import annotations
 
@@ -47,15 +52,18 @@ class MoEParams(NamedTuple):
 
 
 def init_moe_params(key, cfg, dtype) -> MoEParams:
+    """The router over all ``num_experts``; the weights of the experts
+    held here (``num_experts_held``)."""
     from repro.models.layers import dense_init
 
     ks = jax.random.split(key, 4)
     e, d, f = cfg.num_experts, cfg.d_model, cfg.d_ff
+    held = cfg.num_experts_held
     return MoEParams(
         router=dense_init(ks[0], (d, e), dtype=jnp.float32),
-        w_gate=dense_init(ks[1], (e, d, f), in_axis=1, dtype=dtype),
-        w_up=dense_init(ks[2], (e, d, f), in_axis=1, dtype=dtype),
-        w_down=dense_init(ks[3], (e, f, d), in_axis=1, dtype=dtype),
+        w_gate=dense_init(ks[1], (held, d, f), in_axis=1, dtype=dtype),
+        w_up=dense_init(ks[2], (held, d, f), in_axis=1, dtype=dtype),
+        w_down=dense_init(ks[3], (held, f, d), in_axis=1, dtype=dtype),
     )
 
 
@@ -153,3 +161,64 @@ def moe_ffn(p: MoEParams, x: jnp.ndarray, num_experts: int, k: int,
         lambda eo, tg, tp, kp, w: _combine_block(eo, tg, tp, kp, w, tl))(
         expert_out, target, token_of_pair, keep, pb)
     return out.reshape(b, s, d).astype(x.dtype), aux
+
+
+def route(p: MoEParams, xf: jnp.ndarray, k: int, aux_coef: float):
+    """xf (T, d) -> (top_idx (T, k), gates (T, k), aux_loss): the top
+    ``k`` of the router's logits over every expert, gated by a softmax
+    over those ``k``, and the Switch-style load-balance loss."""
+    e = p.router.shape[1]
+    logits = jnp.einsum("td,de->te", xf, p.router,
+                        preferred_element_type=jnp.float32)
+    top_logits, top_idx = jax.lax.top_k(logits, k)
+    gates = jax.nn.softmax(top_logits, axis=-1)
+    probs = jax.nn.softmax(logits, axis=-1)
+    ce = jax.nn.one_hot(top_idx[:, 0], e).mean(axis=0)
+    aux = aux_coef * e * jnp.sum(probs.mean(axis=0) * ce)
+    return top_idx, gates, aux
+
+
+def moe_dropless(p: MoEParams, x: jnp.ndarray, k: int,
+                 aux_coef: float = 0.01, first: int = 0):
+    """x: (B, S, d) -> (out (B, S, d), aux_loss, rows).
+
+    Each token is routed over all ``p.router.shape[1]`` experts
+    (``route``). ``p`` holds the weights of experts ``first .. first +
+    E_held - 1``; the result is what those experts give, for every token
+    routed to them (none is dropped). ``rows`` counts the (token,
+    expert) pairs handed to the grouped products.
+    """
+    b, s, d = x.shape
+    t = b * s
+    held = p.w_gate.shape[0]
+    xf = x.reshape(t, d)
+    top_idx, gates, aux = route(p, xf, k, aux_coef)
+
+    # pairs of held experts first, sorted by expert; the rest after them
+    local = top_idx.reshape(-1) - first
+    is_held = (local >= 0) & (local < held)
+    group = jnp.where(is_held, local, held)
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
+    rows = jnp.sum(sizes)
+    gate = jnp.where(is_held, gates.reshape(-1), 0.0)[order]
+
+    xs = xf[order // k]  # (T*k, d): the token of each sorted pair
+    g = jax.lax.ragged_dot(xs, p.w_gate, sizes)
+    u = jax.lax.ragged_dot(xs, p.w_up, sizes)
+    h = jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)
+    h = (h * gate[:, None]).astype(xs.dtype)
+    y = jax.lax.ragged_dot(h, p.w_down, sizes)
+    # rows past the held pairs belong to no group: whatever the grouped
+    # product left there is not a result
+    y = jnp.where((jnp.arange(t * k) < rows)[:, None], y, 0)
+    inv = jnp.argsort(order)
+    out = jnp.sum(y[inv].reshape(t, k, d).astype(jnp.float32), axis=1)
+    return out.reshape(b, s, d).astype(x.dtype), aux, rows
+
+
+def shared_expert(p: dict, x: jnp.ndarray) -> jnp.ndarray:
+    """The always-on shared expert: a SwiGLU MLP over every token."""
+    from repro.models.layers import swiglu
+
+    return swiglu(x, p["w_gate"], p["w_up"], p["w_down"])

@@ -16,6 +16,12 @@ Key structural decisions (they determine compile time and shardability):
   Mamba2 trunk layers + one application of the *shared-weight* attention
   block (closure params — the defining Zamba2 trick), with a scanned tail
   for the remainder.
+* **Mixed (granite-4.0-h)**: Mamba2 and attention layers with their own
+  weights, each followed by its MoE. Parameters are stacked per kind;
+  the depth loop is a scan over whole periods of ``block_pattern``, and
+  inside a period each run of same-kind layers is a scan of its own. The
+  decode cache holds the Mamba layers' SSM and conv states and the
+  attention layers' K/V side by side.
 """
 from __future__ import annotations
 
@@ -39,7 +45,13 @@ from repro.models.attention import (
 )
 from repro.models.layers import dense_init, rms_norm, softcap, swiglu
 from repro.models.partitioning import shard_act
-from repro.models.moe import MoEParams, init_moe_params, moe_ffn
+from repro.models.moe import (
+    MoEParams,
+    init_moe_params,
+    moe_dropless,
+    moe_ffn,
+    shared_expert,
+)
 from repro.models.ssm import (
     MambaParams,
     init_mamba_params,
@@ -62,18 +74,29 @@ def _init_mlp(key, d, ff, dtype):
     }
 
 
+def _init_ffn(key, cfg: ModelConfig, dtype) -> dict:
+    """The norm and MLP (dense or MoE, and the shared expert) after a
+    mixer."""
+    km, ks = jax.random.split(key)
+    layer = {"ln2": jnp.zeros((cfg.d_model,), dtype)}
+    if cfg.is_moe:
+        layer["moe"] = init_moe_params(km, cfg, dtype)
+    else:
+        layer["mlp"] = _init_mlp(km, cfg.d_model, cfg.d_ff, dtype)
+    if cfg.shared_expert_ff:
+        layer["shared"] = _init_mlp(ks, cfg.d_model, cfg.shared_expert_ff,
+                                    dtype)
+    return layer
+
+
 def _init_attn_layer(cfg: ModelConfig, dtype, with_cross: bool):
     def init_one(key):
         ka, km, kc = jax.random.split(key, 3)
         layer = {
             "ln1": jnp.zeros((cfg.d_model,), dtype),
             "attn": init_attn_params(ka, cfg, dtype),
-            "ln2": jnp.zeros((cfg.d_model,), dtype),
+            **_init_ffn(km, cfg, dtype),
         }
-        if cfg.is_moe:
-            layer["moe"] = init_moe_params(km, cfg, dtype)
-        else:
-            layer["mlp"] = _init_mlp(km, cfg.d_model, cfg.d_ff, dtype)
         if cfg.use_post_norm:
             layer["post_ln1"] = jnp.zeros((cfg.d_model,), dtype)
             layer["post_ln2"] = jnp.zeros((cfg.d_model,), dtype)
@@ -87,10 +110,14 @@ def _init_attn_layer(cfg: ModelConfig, dtype, with_cross: bool):
 
 def _init_mamba_layer(cfg: ModelConfig, dtype):
     def init_one(key):
-        return {
+        km, kf = jax.random.split(key)
+        layer = {
             "ln": jnp.zeros((cfg.d_model,), dtype),
-            "mamba": init_mamba_params(key, cfg, dtype),
+            "mamba": init_mamba_params(km, cfg, dtype),
         }
+        if cfg.is_mixed:
+            layer.update(_init_ffn(kf, cfg, dtype))
+        return layer
 
     return init_one
 
@@ -118,8 +145,16 @@ def init_params(cfg: ModelConfig, key) -> PyTree:
     elif all(k == MAMBA for k in kinds):
         init_one = _init_mamba_layer(cfg, dtype)
         params["layers"] = jax.vmap(init_one)(layer_keys)
+    elif cfg.is_mixed:
+        kinds_arr = np.array(kinds)
+        params["layers"] = {
+            MAMBA: jax.vmap(_init_mamba_layer(cfg, dtype))(
+                layer_keys[kinds_arr == MAMBA]),
+            ATTN: jax.vmap(_init_attn_layer(cfg, dtype, with_cross=False))(
+                layer_keys[kinds_arr == ATTN]),
+        }
     else:
-        raise ValueError(f"mixed per-layer patterns unsupported: {cfg.name}")
+        raise ValueError(f"unsupported layer pattern: {cfg.name}")
 
     if cfg.shared_attn_every:
         # zamba2: ONE shared-weight attention+MLP block
@@ -167,6 +202,8 @@ def _embed_in(params, cfg: ModelConfig, tokens=None, embeds=None):
         x = params["embed"][tokens].astype(adt)
     if cfg.scale_embeddings:
         x = x * jnp.asarray(np.sqrt(cfg.d_model), adt)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, adt)
     return shard_act(x, ("batch", "seq", "embed"))
 
 
@@ -176,6 +213,8 @@ def _unembed(params, cfg: ModelConfig, x):
     if head is None:
         head = params["embed"].T
     logits = jnp.einsum("bsd,dv->bsv", x, head).astype(jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     logits = shard_act(logits, ("batch", "seq", "vocab"))
     if cfg.final_logit_softcap is not None:
         logits = softcap(logits, cfg.final_logit_softcap)
@@ -221,17 +260,7 @@ def _attn_layer(cfg: ModelConfig, mode: str):
             hc = rms_norm(x, lp["ln_cross"], cfg.norm_eps)
             x = x + cross_attend(cp, hc, xs["cross_k"], xs["cross_v"], cfg)
 
-        h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
-        if cfg.is_moe:
-            mp = MoEParams(*lp["moe"]) if not isinstance(
-                lp["moe"], MoEParams) else lp["moe"]
-            m_out, aux = moe_ffn(mp, h2, cfg.num_experts,
-                                 cfg.experts_per_token, cfg.router_aux_coef,
-                                 cfg.moe_capacity_factor)
-        else:
-            mlp = lp["mlp"]
-            m_out = swiglu(h2, mlp["w_gate"], mlp["w_up"], mlp["w_down"])
-            aux = jnp.zeros((), jnp.float32)
+        m_out, aux, _ = _ffn(cfg, lp, x)
         if cfg.use_post_norm:
             m_out = rms_norm(m_out, lp["post_ln2"], cfg.norm_eps)
         x = x + m_out
@@ -239,6 +268,33 @@ def _attn_layer(cfg: ModelConfig, mode: str):
         return x, ys
 
     return body
+
+
+def _ffn(cfg: ModelConfig, lp, x):
+    """The MLP branch of a layer on ``norm(x)``: dense, dropping MoE or
+    dropless MoE, plus the shared expert. Returns (branch, router aux
+    loss, (token, expert) pairs handed to the held experts)."""
+    h = rms_norm(x, lp["ln2"], cfg.norm_eps)
+    aux = jnp.zeros((), jnp.float32)
+    rows = jnp.zeros((), jnp.int32)
+    with jax.named_scope("moe" if cfg.is_moe else "mlp"):
+        if cfg.is_moe:
+            mp = MoEParams(*lp["moe"]) if not isinstance(
+                lp["moe"], MoEParams) else lp["moe"]
+            if cfg.moe_capacity_factor:
+                out, aux = moe_ffn(mp, h, cfg.num_experts,
+                                   cfg.experts_per_token,
+                                   cfg.router_aux_coef,
+                                   cfg.moe_capacity_factor)
+            else:
+                out, aux, rows = moe_dropless(mp, h, cfg.experts_per_token,
+                                              cfg.router_aux_coef)
+        else:
+            mlp = lp["mlp"]
+            out = swiglu(h, mlp["w_gate"], mlp["w_up"], mlp["w_down"])
+        if "shared" in lp:
+            out = out + shared_expert(lp["shared"], h)
+    return out, aux, rows
 
 
 def _scan_attn_layers(params, cfg, x, mode, *, cache=None, cache_pos=None,
@@ -302,15 +358,7 @@ def _mlp_and_residual(cfg, lp, x, a_out):
     if cfg.use_post_norm:
         a_out = rms_norm(a_out, lp["post_ln1"], cfg.norm_eps)
     x = x + a_out
-    h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
-    if cfg.is_moe:
-        mp = MoEParams(*lp["moe"]) if not isinstance(
-            lp["moe"], MoEParams) else lp["moe"]
-        m_out, _ = moe_ffn(mp, h2, cfg.num_experts, cfg.experts_per_token,
-                           cfg.router_aux_coef, cfg.moe_capacity_factor)
-    else:
-        mlp = lp["mlp"]
-        m_out = swiglu(h2, mlp["w_gate"], mlp["w_up"], mlp["w_down"])
+    m_out, _, _ = _ffn(cfg, lp, x)
     if cfg.use_post_norm:
         m_out = rms_norm(m_out, lp["post_ln2"], cfg.norm_eps)
     return x + m_out
@@ -610,6 +658,116 @@ def _run_hybrid(params, cfg, x, mode, *, cache=None, cache_pos=None,
 
 
 # ---------------------------------------------------------------------------
+# Mixed (granite-4.0-h): Mamba2 and attention layers with their own weights,
+# each followed by its MLP/MoE
+# ---------------------------------------------------------------------------
+def _mixed_runs(cfg: ModelConfig):
+    """The period's runs of same-kind layers: (kind, first index among
+    the period's layers of that kind, length)."""
+    runs, seen = [], {MAMBA: 0, ATTN: 0}
+    for kind in cfg.block_pattern:
+        if runs and runs[-1][0] == kind:
+            runs[-1][2] += 1
+        else:
+            runs.append([kind, seen[kind], 1])
+        seen[kind] += 1
+    return [tuple(r) for r in runs]
+
+
+def _mixed_layer(cfg: ModelConfig, kind: str, mode: str, cache_pos=None,
+                 cache_len=None):
+    """body(x, xs) -> (x, ys) of one layer: ``x + r * mixer(norm(x))``,
+    then ``x + r * (moe(norm(x)) + shared(norm(x)))``, ``r`` the residual
+    multiplier. ``xs`` holds the layer's params (``p``) and, in decode,
+    its cache; ``ys`` its new cache, router aux loss and expert rows."""
+    r = cfg.residual_multiplier
+
+    def body(x, xs):
+        lp, ys = xs["p"], {}
+        if kind == MAMBA:
+            mp = MambaParams(*lp["mamba"]) if not isinstance(
+                lp["mamba"], MambaParams) else lp["mamba"]
+            h = rms_norm(x, lp["ln"], cfg.norm_eps)
+            with jax.named_scope("mamba"):
+                out, (ys["ssm"], ys["conv"]) = mamba_block(
+                    mp, h, cfg, ssm_state=xs.get("ssm"),
+                    conv_state=xs.get("conv"), decode=mode == "decode")
+        else:
+            ap = AttnParams(*lp["attn"]) if not isinstance(
+                lp["attn"], AttnParams) else lp["attn"]
+            h = rms_norm(x, lp["ln1"], cfg.norm_eps)
+            window = jnp.asarray(0, jnp.int32)  # global
+            theta = jnp.asarray(cfg.rope_theta, jnp.float32)
+            with jax.named_scope("attn"):
+                if mode == "full":
+                    out = attn_mod.attend_full(ap, h, cfg, window=window,
+                                               theta=theta)
+                elif mode == "prefill":
+                    out, ys["k"], ys["v"] = attn_mod.prefill(
+                        ap, h, cfg, window=window, theta=theta,
+                        cache_len=cache_len)
+                else:
+                    out, ys["k"], ys["v"] = attn_mod.decode_step(
+                        ap, h, xs["k"], xs["v"], cache_pos, cfg,
+                        window=window, theta=theta)
+        x = x + r * out
+        m_out, ys["aux"], ys["rows"] = _ffn(cfg, lp, x)
+        return x + r * m_out, ys
+
+    return body
+
+
+def _run_mixed(params, cfg, x, mode, *, cache=None, cache_pos=None,
+               cache_len=None, remat=False):
+    """Scan over whole periods; inside one, a scan per run of same-kind
+    layers. Returns (x, cache, aux, expert rows)."""
+    period = cfg.block_pattern
+    n_per = cfg.num_layers // len(period)
+    count = {k: period.count(k) for k in (MAMBA, ATTN)}
+    state = {MAMBA: ("ssm", "conv"), ATTN: ("k", "v")}
+
+    def fold(kind, a):
+        return a.reshape((n_per, count[kind]) + a.shape[1:])
+
+    xs = {kind: {"p": jax.tree.map(functools.partial(fold, kind),
+                                   params["layers"][kind])}
+          for kind in (MAMBA, ATTN)}
+    if mode == "decode":
+        for kind in (MAMBA, ATTN):
+            for name in state[kind]:
+                xs[kind][name] = fold(kind, cache[name])
+    bodies = {}
+    for kind in (MAMBA, ATTN):
+        body = _mixed_layer(cfg, kind, mode, cache_pos, cache_len)
+        bodies[kind] = jax.checkpoint(body) if remat else body
+
+    def period_body(x, pxs):
+        outs = {MAMBA: [], ATTN: []}
+        for kind, start, n in _mixed_runs(cfg):
+            run_xs = jax.tree.map(lambda a: a[start:start + n], pxs[kind])
+            if n == 1:
+                x, ys = bodies[kind](
+                    x, jax.tree.map(lambda a: a[0], run_xs))
+                ys = jax.tree.map(lambda a: a[None], ys)
+            else:
+                x, ys = jax.lax.scan(bodies[kind], x, run_xs)
+            outs[kind].append(ys)
+        ys = {kind: jax.tree.map(lambda *a: jnp.concatenate(a), *outs[kind])
+              for kind in (MAMBA, ATTN)}
+        return x, ys
+
+    x, ys = jax.lax.scan(period_body, x, xs)
+    aux = sum(jnp.sum(ys[k]["aux"]) for k in (MAMBA, ATTN))
+    rows = sum(jnp.sum(ys[k]["rows"]) for k in (MAMBA, ATTN))
+    new_cache = None
+    if mode in ("prefill", "decode"):
+        unfold = lambda a: a.reshape((-1,) + a.shape[2:])  # noqa: E731
+        new_cache = {name: unfold(ys[kind][name])
+                     for kind in (MAMBA, ATTN) for name in state[kind]}
+    return x, new_cache, aux, rows
+
+
+# ---------------------------------------------------------------------------
 # Encoder (whisper)
 # ---------------------------------------------------------------------------
 def encode(params, cfg: ModelConfig, enc_embeds):
@@ -654,7 +812,19 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int,
     cache: dict = {}
     if uses_ring_cache(cfg):
         return init_ring_cache(cfg, batch, max_seq, dtype)
-    if cfg.shared_attn_every:  # hybrid
+    if cfg.is_mixed:
+        d_in, n_heads, conv_dim = mamba_dims(cfg)
+        n_mamba = sum(1 for kk in kinds if kk == MAMBA)
+        n_attn = cfg.num_layers - n_mamba
+        cache["ssm"] = jnp.zeros(
+            (n_mamba, batch, n_heads, cfg.ssm_head_dim, cfg.ssm_state_size),
+            jnp.float32)
+        cache["conv"] = jnp.zeros(
+            (n_mamba, batch, cfg.ssm_conv_width - 1, conv_dim), dtype)
+        cache["k"] = jnp.zeros(
+            (n_attn, batch, max_seq, cfg.num_kv_heads, cfg.head_dim), dtype)
+        cache["v"] = jnp.zeros_like(cache["k"])
+    elif cfg.shared_attn_every:  # hybrid
         d_in, n_heads, conv_dim = mamba_dims(cfg)
         k, n_super, tail = _zamba_split(cfg)
         cache["ssm"] = jnp.zeros(
@@ -688,24 +858,45 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int,
 # ---------------------------------------------------------------------------
 # Public forward
 # ---------------------------------------------------------------------------
+def _trunk(params, cfg: ModelConfig, x, mode: str, *, cache=None,
+           cache_pos=None, cache_len=None, cross=None, remat=False):
+    """Every layer, for each family. Returns (x, cache, aux, expert
+    rows handed to the dropless MoE's grouped products)."""
+    no_rows = jnp.zeros((), jnp.int32)
+    if mode == "decode" and cache is not None and "ring_k" in cache:
+        return (*_scan_ring_decode(params, cfg, x, cache, cache_pos),
+                no_rows)
+    if cfg.is_mixed:
+        return _run_mixed(params, cfg, x, mode, cache=cache,
+                          cache_pos=cache_pos, cache_len=cache_len,
+                          remat=remat)
+    if cfg.shared_attn_every:
+        return (*_run_hybrid(params, cfg, x, mode, cache=cache,
+                             cache_pos=cache_pos, cache_len=cache_len,
+                             remat=remat), no_rows)
+    if all(k == MAMBA for k in cfg.layer_kinds()):
+        # mamba "prefill" == full forward; final states are the cache
+        return (*_scan_mamba_layers(
+            params, cfg, x, mode if mode != "prefill" else "full",
+            cache=cache, remat=remat), no_rows)
+    return (*_scan_attn_layers(params, cfg, x, mode, cache=cache,
+                               cache_pos=cache_pos, cache_len=cache_len,
+                               cross=cross, remat=remat), no_rows)
+
+
 def hidden_states(params, cfg: ModelConfig, tokens=None, embeds=None,
                   enc_embeds=None, remat: bool = False):
     """Final-layer hidden states (pre-unembed) — the frozen-backbone
-    embedding interface used by the preference pipeline."""
+    embedding interface used by the preference pipeline. Returns
+    (hidden (B, S, d), router aux loss, expert rows)."""
     x = _embed_in(params, cfg, tokens=tokens, embeds=embeds)
-    kinds = cfg.layer_kinds()
     cross = None
     if cfg.is_encoder_decoder:
         enc_out = encode(params, cfg, enc_embeds)
         cross = _stacked_cross_kv(params, cfg, enc_out)
-    if cfg.shared_attn_every:
-        x, _, aux = _run_hybrid(params, cfg, x, "full", remat=remat)
-    elif all(k == MAMBA for k in kinds):
-        x, _, aux = _scan_mamba_layers(params, cfg, x, "full", remat=remat)
-    else:
-        x, _, aux = _scan_attn_layers(params, cfg, x, "full", cross=cross,
-                                      remat=remat)
-    return rms_norm(x, params["final_norm"], cfg.norm_eps), aux
+    x, _, aux, rows = _trunk(params, cfg, x, "full", cross=cross,
+                             remat=remat)
+    return rms_norm(x, params["final_norm"], cfg.norm_eps), aux, rows
 
 
 def forward(params, cfg: ModelConfig, *, tokens=None, embeds=None,
@@ -719,9 +910,6 @@ def forward(params, cfg: ModelConfig, *, tokens=None, embeds=None,
       * decode      : cache + cache_pos, S==1      -> (logits, cache, aux)
     """
     x = _embed_in(params, cfg, tokens=tokens, embeds=embeds)
-    kinds = cfg.layer_kinds()
-    is_mamba = all(k == MAMBA for k in kinds)
-    is_hybrid = bool(cfg.shared_attn_every)
 
     mode = "full"
     if prefill_len is not None:
@@ -737,22 +925,8 @@ def forward(params, cfg: ModelConfig, *, tokens=None, embeds=None,
             enc_out = encode(params, cfg, enc_embeds)
             cross = _stacked_cross_kv(params, cfg, enc_out)
 
-    if mode == "decode" and cache is not None and "ring_k" in cache:
-        x, new_cache, aux = _scan_ring_decode(params, cfg, x, cache,
-                                              cache_pos)
-    elif is_hybrid:
-        x, new_cache, aux = _run_hybrid(
-            params, cfg, x, mode, cache=cache, cache_pos=cache_pos,
-            cache_len=prefill_len, remat=remat)
-    elif is_mamba:
-        x, new_cache, aux = _scan_mamba_layers(
-            params, cfg, x, mode if mode != "prefill" else "full",
-            cache=cache, remat=remat)
-        # mamba "prefill" == full forward; final states are the cache
-    else:
-        x, new_cache, aux = _scan_attn_layers(
-            params, cfg, x, mode, cache=cache, cache_pos=cache_pos,
-            cache_len=prefill_len, cross=cross, remat=remat)
-
+    x, new_cache, aux, _ = _trunk(params, cfg, x, mode, cache=cache,
+                                  cache_pos=cache_pos, cache_len=prefill_len,
+                                  cross=cross, remat=remat)
     logits = _unembed(params, cfg, x)
     return logits, new_cache, aux

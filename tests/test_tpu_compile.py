@@ -9,7 +9,10 @@ the worker that runs this file loads the TPU compiler.
 
 Sizes: S = (16 + 16) questions x 5 options = 160 attention points,
 ``GPOConfig`` heads H = 4 of width hd = 32, C = 10 clients and
-P = 1,050,112 parameters (the predictor at ``d_embed=4096``).
+P = 1,050,112 parameters (the predictor at ``d_embed=4096``). The
+dropless MoE's grouped expert products (the TPU's ``ragged-dot`` custom
+call) compile at granite-4.0-h-small's widths: 12 texts of 128 tokens
+routed top-10 over 72 experts, 18 of them held.
 """
 import os
 
@@ -28,10 +31,12 @@ from repro.kernels.ops import (
     gpo_attention,
     int8_matmul,
 )
+from repro.models.moe import MoEParams, moe_dropless
 
 S, H, HD, NUM_CTX = 160, 4, 32, 80
 C, P = 10, 1_050_112
 M, K, N = 640, 4098, 128  # served target points x (d_embed + 2) x d_model
+T, D, E, HELD, FF = 1536, 4096, 72, 18, 768  # granite-4.0-h-small MoE
 
 
 @pytest.fixture(scope="module")
@@ -100,6 +105,11 @@ CASES = {
     "int8_matmul": (
         lambda x, q, s: int8_matmul(x, q, s, interpret=False),
         [((M, K), jnp.float32), ((K, N), jnp.int8), ((N,), jnp.float32)]),
+    "moe_dropless": (
+        lambda x, *p: moe_dropless(MoEParams(*p), x, 10)[0],
+        [((1, T, D), jnp.bfloat16), ((D, E), jnp.bfloat16)]
+        + [((HELD, D, FF), jnp.bfloat16)] * 2
+        + [((HELD, FF, D), jnp.bfloat16)]),
 }
 
 
