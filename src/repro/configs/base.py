@@ -507,10 +507,15 @@ class ServeConfig:
     # quantize the predictor's dense weights to int8 at load time and
     # serve through the fused int8 matmul kernel (DESIGN.md §12)
     int8_weights: bool = False
-    # requests whose targets are token texts: each text pads to the
-    # smallest length bucket that fits (tokens), and a step's texts of
-    # one length bucket run through the backbone in groups padded to a
-    # row bucket, so the embed programs are len x len of these
+    # requests whose targets are token texts: texts share backbone rows.
+    # A step's texts are packed (first-fit decreasing) into rows whose
+    # length is the smallest text bucket that holds the longest text, at
+    # most row length // text_buckets[0] texts a row (4 at 512 of 128),
+    # kept apart by segment ids; the row count pads to a row bucket (a
+    # step needing more rows than the largest splits). The embed
+    # programs are the row lengths x row counts of these. An embedder
+    # with no segment path (encoder-decoder, shared-attention hybrid)
+    # takes one text a row, grouped by its own length bucket.
     text_buckets: Tuple[int, ...] = (128, 256, 512)
     text_row_buckets: Tuple[int, ...] = (12, 20, 28)
 

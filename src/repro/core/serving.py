@@ -35,10 +35,12 @@ query load. This module turns the single-tenant, synchronous
 * **Token-text targets** — with an ``embedder`` (a frozen backbone's
   config and params), a request may carry its targets as token texts
   (``tgt_tokens``) in place of embeddings. ``step`` embeds the batch's
-  texts on the device, grouped by length bucket (one program per length
-  and row bucket), as the masked mean pool of the backbone's final-normed
-  hidden states, unit-normed, and places them into the decode input
-  there: no embedding comes back to the host before the decode.
+  texts on the device, packed several to a backbone row (first-fit
+  decreasing, kept apart inside the row by segment ids; one program per
+  row length and row bucket), as the masked mean pool of the backbone's
+  final-normed hidden states, unit-normed, and places them into the
+  decode input there: no embedding comes back to the host before the
+  decode.
 
 Everything timing-related is measurement only: scheduling decisions
 depend exclusively on queue order, so a fixed arrival trace yields a
@@ -60,7 +62,7 @@ from repro.configs.base import GPOConfig, ModelConfig, ServeConfig
 from repro.core.gpo import GPOLayer, GPOPrefix, gpo_decode, gpo_prefill
 from repro.data.embeddings import masked_mean, unit_norm
 from repro.kernels import quantize_linear
-from repro.models.transformer import hidden_states
+from repro.models.transformer import has_segment_path, hidden_states
 from repro.utils.spans import span
 
 PyTree = Any
@@ -208,17 +210,26 @@ def _decode_batch(params, cfg: GPOConfig, num_options: int, ctx_b: int,
         return jax.vmap(one)(pk, pv, ctx_len, tgt_x)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def _embed_texts(params, cfg: ModelConfig, tokens, lens, rows_total):
-    """(R, L) token texts padded on the right, (R,) lengths (0 for a
-    padding row) -> (unit embeddings (R, d), pooled embeddings before
-    the norm (R, d) f32, ``rows_total`` plus the (token, expert) pairs
-    the backbone's dropless MoE handed to its grouped products)."""
+@functools.partial(jax.jit, static_argnames=("cfg", "per_row"))
+def _embed_texts(params, cfg: ModelConfig, tokens, segment_ids, rows_total,
+                 per_row: int):
+    """(R, L) rows of token texts and their (R, L) segment ids: text k of
+    a row (k = 1..``per_row``) is the row's tokens of segment k, 0 the
+    unused tail -> (unit embeddings (R * per_row, d), pooled embeddings
+    before the norm (R * per_row, d) f32, ``rows_total`` plus the
+    (token, expert) pairs the backbone's dropless MoE handed to its
+    grouped products). Pooled row ``r * per_row + k - 1`` is text k of
+    row r: the float32 mean over its tokens, 0 for an empty segment.
+    With one text a row the trunk takes no segment ids: the text is
+    causal and the tail follows it."""
     with jax.named_scope("backbone"):
-        hidden, _, rows = hidden_states(params, cfg, tokens=tokens)
-    mask = (jnp.arange(tokens.shape[1])[None, :] < lens[:, None])
-    pooled = masked_mean(hidden.astype(jnp.float32),
-                         mask.astype(jnp.float32))
+        hidden, _, rows = hidden_states(
+            params, cfg, tokens=tokens,
+            segment_ids=segment_ids if per_row > 1 else None)
+    hidden = hidden.astype(jnp.float32)
+    pooled = jnp.stack(
+        [masked_mean(hidden, (segment_ids == k).astype(jnp.float32))
+         for k in range(1, per_row + 1)], axis=1).reshape(-1, hidden.shape[-1])
     return unit_norm(pooled), pooled, rows_total + rows
 
 
@@ -230,6 +241,27 @@ def _place_texts(tgt_x, emb, slots):
     flat = tgt_x.reshape(b * t, d).at[slots].set(
         emb.astype(tgt_x.dtype), mode="drop")
     return flat.reshape(b, t, d)
+
+
+def pack_rows(lengths: Sequence[int], row_len: int,
+              per_row: int) -> List[List[int]]:
+    """First-fit decreasing: the indices of ``lengths`` (each at most
+    ``row_len``) in rows of at most ``row_len`` tokens and ``per_row``
+    texts, longest first (ties in the order given), each into the first
+    row it fits. A pure function of ``lengths``: batches replay."""
+    rows: List[List[int]] = []
+    used: List[int] = []
+    for i in sorted(range(len(lengths)), key=lambda i: -lengths[i]):
+        n = lengths[i]
+        for r, row in enumerate(rows):
+            if len(row) < per_row and used[r] + n <= row_len:
+                row.append(i)
+                used[r] += n
+                break
+        else:
+            rows.append([i])
+            used.append(n)
+    return rows
 
 
 def _bucket_of(n: int, buckets: Sequence[int], what: str) -> int:
@@ -453,41 +485,65 @@ class PreferenceServer:
                     self.stats.completed += 1
             return out
 
+    def _text_rows(self, texts):
+        """The rows of ``texts`` (slot, tokens, length), as (row length,
+        texts a row, rows of indices into ``texts``) per row length.
+        Where the embedder has a segment path (``has_segment_path``),
+        every text shares one row length, the smallest ``text_buckets``
+        entry that holds the longest, and rows of up to ``row length //
+        text_buckets[0]`` texts (``pack_rows``); otherwise each text has
+        a row of its own length bucket."""
+        cfg, sc = self.embedder[0], self.scfg
+        lengths = [n for _, _, n in texts]
+        if has_segment_path(cfg):
+            row_len = _bucket_of(max(lengths), sc.text_buckets, "text")
+            per_row = row_len // sc.text_buckets[0]
+            return [(row_len, per_row, pack_rows(lengths, row_len, per_row))]
+        by_len: dict = {}
+        for i, n in enumerate(lengths):
+            by_len.setdefault(_bucket_of(n, sc.text_buckets, "text"),
+                              []).append([i])
+        return [(row_len, 1, rows) for row_len, rows in sorted(by_len.items())]
+
     def _embed_groups(self, texts, n_slots: int):
-        """Run ``texts`` (slot, tokens, length) through the embedder: the
-        texts of one length bucket (``text_buckets``) together, at most
-        ``text_row_buckets[-1]`` a call, padded to a row bucket. Yields
-        (unit embeddings, pooled embeddings before the norm, slots) per
-        call, all on the device; padding rows get slot ``n_slots``.
-        Each call is a ``serve.embed`` span counting its ``texts``, real
-        ``tokens`` and ``computed`` (padded) tokens."""
+        """Run ``texts`` (slot, tokens, length) through the embedder in
+        the rows of ``_text_rows``, at most ``text_row_buckets[-1]`` rows
+        a call, padded to a row bucket. Yields (unit embeddings, pooled
+        embeddings before the norm, slots) per call, all on the device,
+        one entry per (row, text place); empty places get slot
+        ``n_slots``. Each call is a ``serve.embed`` span counting its
+        ``texts``, real ``tokens``, ``rows`` and ``computed`` tokens
+        (row bucket x row length)."""
         if not texts:
             return
         if self.embedder is None:
             raise ValueError("token texts need a server built with an "
                              "embedder")
         cfg, params = self.embedder
-        sc = self.scfg
-        by_len: dict = {}
-        for item in texts:
-            by_len.setdefault(_bucket_of(item[2], sc.text_buckets, "text"),
-                              []).append(item)
-        most = sc.text_row_buckets[-1]
-        for length, items in sorted(by_len.items()):
-            for start in range(0, len(items), most):
-                chunk = items[start:start + most]
-                rb = _bucket_of(len(chunk), sc.text_row_buckets, "text_row")
-                with span("serve.embed", texts=len(chunk),
-                          tokens=sum(n for _, _, n in chunk),
-                          computed=rb * length):
-                    toks = np.zeros((rb, length), np.int32)
-                    lens = np.zeros(rb, np.int32)
-                    slots = np.full(rb, n_slots, np.int32)
-                    for k, (slot, tok, n) in enumerate(chunk):
-                        toks[k, :n], lens[k], slots[k] = tok[:n], n, slot
-                    toks, lens, slots = jax.device_put((toks, lens, slots))
+        most = self.scfg.text_row_buckets[-1]
+        for row_len, per_row, all_rows in self._text_rows(texts):
+            for start in range(0, len(all_rows), most):
+                rows = all_rows[start:start + most]
+                rb = _bucket_of(len(rows), self.scfg.text_row_buckets,
+                                "text_row")
+                with span("serve.embed", texts=sum(map(len, rows)),
+                          tokens=sum(texts[i][2] for row in rows
+                                     for i in row),
+                          rows=len(rows), computed=rb * row_len):
+                    toks = np.zeros((rb, row_len), np.int32)
+                    segs = np.zeros((rb, row_len), np.int32)
+                    slots = np.full(rb * per_row, n_slots, np.int32)
+                    for r, row in enumerate(rows):
+                        at = 0
+                        for k, i in enumerate(row):
+                            slot, tok, n = texts[i]
+                            toks[r, at:at + n] = tok[:n]
+                            segs[r, at:at + n] = k + 1
+                            slots[r * per_row + k] = slot
+                            at += n
+                    toks, segs, slots = jax.device_put((toks, segs, slots))
                     unit, pooled, self.expert_rows = _embed_texts(
-                        params, cfg, toks, lens, self.expert_rows)
+                        params, cfg, toks, segs, self.expert_rows, per_row)
                 yield unit, pooled, slots
 
     def embed(self, tokens: Sequence[np.ndarray]):

@@ -144,6 +144,16 @@ def _scaled(scores, hd, scale):
     return scores * jnp.asarray(scale, jnp.float32)
 
 
+def segment_positions(segment_ids):
+    """(b, s) segment ids, each id's tokens contiguous -> each token's
+    position within its segment (0 at a segment's first token)."""
+    idx = jnp.arange(segment_ids.shape[1], dtype=jnp.int32)
+    first = jnp.concatenate(
+        [jnp.ones_like(segment_ids[:, :1], bool),
+         segment_ids[:, 1:] != segment_ids[:, :-1]], axis=1)
+    return idx - jax.lax.cummax(jnp.where(first, idx, 0), axis=1)
+
+
 def make_causal_window_mask(q_pos, k_pos, window, k_valid=None):
     """bool mask (b?, sq, sk). window traced scalar; <=0 means global."""
     causal = k_pos[..., None, :] <= q_pos[..., :, None]
@@ -162,12 +172,14 @@ CHUNK_THRESHOLD = 8192
 Q_CHUNK = 1024
 
 
-def _chunked_gqa(q, k, v, window, logit_cap, q_chunk: int, scale=None):
+def _chunked_gqa(q, k, v, window, logit_cap, q_chunk: int, scale=None,
+                 segment_ids=None):
     """Causal/windowed attention, scanning q in chunks of ``q_chunk``.
 
     q (b,s,h,hd) and k/v (b,s,kv,hd) are already roped. Peak score memory
     drops from O(S^2) to O(q_chunk * S) — the XLA-level analogue of the
-    Pallas flash kernel (which replaces this on real TPUs).
+    Pallas flash kernel (which replaces this on real TPUs). A query sees
+    only keys of its own segment where ``segment_ids`` (b,s) is given.
     """
     b, s, h, hd = q.shape
     assert s % q_chunk == 0, (s, q_chunk)
@@ -180,6 +192,10 @@ def _chunked_gqa(q, k, v, window, logit_cap, q_chunk: int, scale=None):
         q_pos = (i * q_chunk
                  + jnp.arange(q_chunk, dtype=jnp.int32))[None, :].repeat(b, 0)
         mask = make_causal_window_mask(q_pos, k_pos, window)
+        if segment_ids is not None:
+            seg_q = jax.lax.dynamic_slice_in_dim(segment_ids, i * q_chunk,
+                                                 q_chunk, axis=1)
+            mask = mask & (seg_q[:, :, None] == segment_ids[:, None, :])
         out = gqa_scores_softmax(qblk, k, v, mask, logit_cap, scale)
         return None, out
 
@@ -187,22 +203,31 @@ def _chunked_gqa(q, k, v, window, logit_cap, q_chunk: int, scale=None):
     return outs.transpose(1, 0, 2, 3, 4).reshape(b, s, h, hd)
 
 
-def attend_full(p: AttnParams, x, cfg, *, window, theta, positions=None):
+def attend_full(p: AttnParams, x, cfg, *, window, theta, positions=None,
+                segment_ids=None):
     """Training / encoder-free full-sequence self attention (no cache).
 
     positions defaults to arange; window/theta may be traced scalars.
+    ``segment_ids`` (b,s), each id's tokens contiguous, packs several
+    texts into a row: a query attends only to earlier keys of its own
+    segment, and positions (for RoPE and the window) restart at each
+    segment's first token unless given.
     """
     b, s, _ = x.shape
     if positions is None:
-        positions = jnp.arange(s, dtype=jnp.int32)[None, :].repeat(b, 0)
+        positions = (jnp.arange(s, dtype=jnp.int32)[None, :].repeat(b, 0)
+                     if segment_ids is None
+                     else segment_positions(segment_ids))
     q, k, v = _project_qkv(p, x, cfg.num_heads, cfg.num_kv_heads,
                            cfg.head_dim, cfg.norm_eps)
     q, k = _rope_qk(q, k, positions, theta, cfg)
     if s >= CHUNK_THRESHOLD and s % Q_CHUNK == 0:
         out = _chunked_gqa(q, k, v, window, cfg.attn_logit_softcap, Q_CHUNK,
-                           cfg.attn_scale)
+                           cfg.attn_scale, segment_ids)
     else:
         mask = make_causal_window_mask(positions, positions, window)
+        if segment_ids is not None:
+            mask = mask & (segment_ids[:, :, None] == segment_ids[:, None, :])
         out = gqa_scores_softmax(q, k, v, mask, cfg.attn_logit_softcap,
                                  cfg.attn_scale)
     return jnp.einsum("bse,ed->bsd", out.reshape(b, s, -1), p.wo)

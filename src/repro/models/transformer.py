@@ -224,9 +224,10 @@ def _unembed(params, cfg: ModelConfig, x):
 # ---------------------------------------------------------------------------
 # Attention-family layer body (train / prefill / decode), scan-compatible
 # ---------------------------------------------------------------------------
-def _attn_layer(cfg: ModelConfig, mode: str):
+def _attn_layer(cfg: ModelConfig, mode: str, segment_ids=None):
     """Returns body(x, xs) -> (x, ys). xs carries layer params + metadata +
-    cache slices; ys carries updated cache slices + moe aux."""
+    cache slices; ys carries updated cache slices + moe aux.
+    ``segment_ids`` (full mode) keeps texts packed into a row apart."""
 
     def body(x, xs):
         lp = xs["layer"]
@@ -237,7 +238,8 @@ def _attn_layer(cfg: ModelConfig, mode: str):
         h = rms_norm(x, lp["ln1"], cfg.norm_eps)
         ys = {}
         if mode == "full":
-            a_out = attn_mod.attend_full(ap, h, cfg, window=window, theta=theta)
+            a_out = attn_mod.attend_full(ap, h, cfg, window=window, theta=theta,
+                                         segment_ids=segment_ids)
         elif mode == "prefill":
             a_out, k, v = attn_mod.prefill(
                 ap, h, cfg, window=window, theta=theta,
@@ -298,7 +300,8 @@ def _ffn(cfg: ModelConfig, lp, x):
 
 
 def _scan_attn_layers(params, cfg, x, mode, *, cache=None, cache_pos=None,
-                      cache_len=None, cross=None, remat=False):
+                      cache_len=None, cross=None, remat=False,
+                      segment_ids=None):
     windows, thetas = layer_windows_thetas(cfg)
     xs = {
         "layer": params["layers"],
@@ -312,7 +315,7 @@ def _scan_attn_layers(params, cfg, x, mode, *, cache=None, cache_pos=None,
     if cross is not None:
         xs["cross_k"], xs["cross_v"] = cross
 
-    body = _attn_layer(cfg, mode)
+    body = _attn_layer(cfg, mode, segment_ids)
     if mode == "prefill":
         # cache_len is a *static* python int (defines cache shapes): closure
         body_inner = body
@@ -522,7 +525,8 @@ def ring_cache_from_full(cfg: ModelConfig, cache: dict, cache_pos: int,
 # ---------------------------------------------------------------------------
 # Mamba-family (pure SSM) scan
 # ---------------------------------------------------------------------------
-def _scan_mamba_layers(params, cfg, x, mode, *, cache=None, remat=False):
+def _scan_mamba_layers(params, cfg, x, mode, *, cache=None, remat=False,
+                       segment_ids=None):
     decode = mode == "decode"
 
     def body(x, xs):
@@ -533,7 +537,7 @@ def _scan_mamba_layers(params, cfg, x, mode, *, cache=None, remat=False):
         out, (ssm_s, conv_s) = mamba_block(
             mp, h, cfg,
             ssm_state=xs.get("ssm"), conv_state=xs.get("conv"),
-            decode=decode)
+            decode=decode, segment_ids=segment_ids)
         return x + out, {"ssm": ssm_s, "conv": conv_s}
 
     xs = {"layer": params["layers"]}
@@ -675,11 +679,13 @@ def _mixed_runs(cfg: ModelConfig):
 
 
 def _mixed_layer(cfg: ModelConfig, kind: str, mode: str, cache_pos=None,
-                 cache_len=None):
+                 cache_len=None, segment_ids=None):
     """body(x, xs) -> (x, ys) of one layer: ``x + r * mixer(norm(x))``,
     then ``x + r * (moe(norm(x)) + shared(norm(x)))``, ``r`` the residual
     multiplier. ``xs`` holds the layer's params (``p``) and, in decode,
-    its cache; ``ys`` its new cache, router aux loss and expert rows."""
+    its cache; ``ys`` its new cache, router aux loss and expert rows.
+    ``segment_ids`` (full mode) keeps texts packed into a row apart in
+    the mixers; the MoE, norms and shared expert act per token."""
     r = cfg.residual_multiplier
 
     def body(x, xs):
@@ -691,7 +697,8 @@ def _mixed_layer(cfg: ModelConfig, kind: str, mode: str, cache_pos=None,
             with jax.named_scope("mamba"):
                 out, (ys["ssm"], ys["conv"]) = mamba_block(
                     mp, h, cfg, ssm_state=xs.get("ssm"),
-                    conv_state=xs.get("conv"), decode=mode == "decode")
+                    conv_state=xs.get("conv"), decode=mode == "decode",
+                    segment_ids=segment_ids)
         else:
             ap = AttnParams(*lp["attn"]) if not isinstance(
                 lp["attn"], AttnParams) else lp["attn"]
@@ -701,7 +708,8 @@ def _mixed_layer(cfg: ModelConfig, kind: str, mode: str, cache_pos=None,
             with jax.named_scope("attn"):
                 if mode == "full":
                     out = attn_mod.attend_full(ap, h, cfg, window=window,
-                                               theta=theta)
+                                               theta=theta,
+                                               segment_ids=segment_ids)
                 elif mode == "prefill":
                     out, ys["k"], ys["v"] = attn_mod.prefill(
                         ap, h, cfg, window=window, theta=theta,
@@ -718,7 +726,7 @@ def _mixed_layer(cfg: ModelConfig, kind: str, mode: str, cache_pos=None,
 
 
 def _run_mixed(params, cfg, x, mode, *, cache=None, cache_pos=None,
-               cache_len=None, remat=False):
+               cache_len=None, remat=False, segment_ids=None):
     """Scan over whole periods; inside one, a scan per run of same-kind
     layers. Returns (x, cache, aux, expert rows)."""
     period = cfg.block_pattern
@@ -738,7 +746,8 @@ def _run_mixed(params, cfg, x, mode, *, cache=None, cache_pos=None,
                 xs[kind][name] = fold(kind, cache[name])
     bodies = {}
     for kind in (MAMBA, ATTN):
-        body = _mixed_layer(cfg, kind, mode, cache_pos, cache_len)
+        body = _mixed_layer(cfg, kind, mode, cache_pos, cache_len,
+                            segment_ids)
         bodies[kind] = jax.checkpoint(body) if remat else body
 
     def period_body(x, pxs):
@@ -858,18 +867,35 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int,
 # ---------------------------------------------------------------------------
 # Public forward
 # ---------------------------------------------------------------------------
+def has_segment_path(cfg: ModelConfig) -> bool:
+    """Whether ``hidden_states`` takes ``segment_ids``, to keep texts
+    packed into one row apart: the mixed family and plain Mamba-2 or
+    decoder-only attention stacks; not the encoder-decoder nor the
+    shared-attention hybrid."""
+    return cfg.is_mixed or (not cfg.is_encoder_decoder
+                            and not cfg.shared_attn_every
+                            and len(set(cfg.layer_kinds())) == 1)
+
+
 def _trunk(params, cfg: ModelConfig, x, mode: str, *, cache=None,
-           cache_pos=None, cache_len=None, cross=None, remat=False):
+           cache_pos=None, cache_len=None, cross=None, remat=False,
+           segment_ids=None):
     """Every layer, for each family. Returns (x, cache, aux, expert
-    rows handed to the dropless MoE's grouped products)."""
+    rows handed to the dropless MoE's grouped products). ``segment_ids``
+    (full mode, families of ``has_segment_path``) keeps packed texts
+    apart."""
     no_rows = jnp.zeros((), jnp.int32)
+    if segment_ids is not None and (mode != "full"
+                                    or not has_segment_path(cfg)):
+        raise ValueError(f"{cfg.name}: segment ids need the full-sequence "
+                         "path of a family with a segment path")
     if mode == "decode" and cache is not None and "ring_k" in cache:
         return (*_scan_ring_decode(params, cfg, x, cache, cache_pos),
                 no_rows)
     if cfg.is_mixed:
         return _run_mixed(params, cfg, x, mode, cache=cache,
                           cache_pos=cache_pos, cache_len=cache_len,
-                          remat=remat)
+                          remat=remat, segment_ids=segment_ids)
     if cfg.shared_attn_every:
         return (*_run_hybrid(params, cfg, x, mode, cache=cache,
                              cache_pos=cache_pos, cache_len=cache_len,
@@ -878,24 +904,30 @@ def _trunk(params, cfg: ModelConfig, x, mode: str, *, cache=None,
         # mamba "prefill" == full forward; final states are the cache
         return (*_scan_mamba_layers(
             params, cfg, x, mode if mode != "prefill" else "full",
-            cache=cache, remat=remat), no_rows)
+            cache=cache, remat=remat, segment_ids=segment_ids), no_rows)
     return (*_scan_attn_layers(params, cfg, x, mode, cache=cache,
                                cache_pos=cache_pos, cache_len=cache_len,
-                               cross=cross, remat=remat), no_rows)
+                               cross=cross, remat=remat,
+                               segment_ids=segment_ids), no_rows)
 
 
 def hidden_states(params, cfg: ModelConfig, tokens=None, embeds=None,
-                  enc_embeds=None, remat: bool = False):
+                  enc_embeds=None, remat: bool = False, segment_ids=None):
     """Final-layer hidden states (pre-unembed) — the frozen-backbone
     embedding interface used by the preference pipeline. Returns
-    (hidden (B, S, d), router aux loss, expert rows)."""
+    (hidden (B, S, d), router aux loss, expert rows).
+
+    ``segment_ids`` (B, S) packs several texts into a row: each id's
+    tokens are contiguous, and every text is computed as if alone, from
+    position 0 (``has_segment_path`` names the families that take it).
+    None is one text a row."""
     x = _embed_in(params, cfg, tokens=tokens, embeds=embeds)
     cross = None
     if cfg.is_encoder_decoder:
         enc_out = encode(params, cfg, enc_embeds)
         cross = _stacked_cross_kv(params, cfg, enc_out)
     x, _, aux, rows = _trunk(params, cfg, x, "full", cross=cross,
-                             remat=remat)
+                             remat=remat, segment_ids=segment_ids)
     return rms_norm(x, params["final_norm"], cfg.norm_eps), aux, rows
 
 

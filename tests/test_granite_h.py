@@ -116,17 +116,24 @@ def test_hidden_states_match_reference(params):
 
 
 def test_pooled_embedding_matches_reference(params):
-    lens = np.array([20, 13, 0], np.int32)
-    tokens = np.asarray(jax.random.randint(
-        jax.random.PRNGKey(4), (3, 24), 0, CFG.vocab_size), np.int32)
-    unit, pooled, _ = _embed_texts(params, CFG, tokens, lens,
-                                   jnp.zeros((), jnp.int32))
+    """Two texts (20 and 13 tokens) packed into one row of 40, two texts
+    a row, and a padding row: each text pools as the reference does it
+    alone."""
+    lens = [20, 13]
+    texts = np.asarray(jax.random.randint(
+        jax.random.PRNGKey(4), (2, 24), 0, CFG.vocab_size), np.int32)
+    tokens = np.zeros((2, 40), np.int32)
+    segs = np.zeros((2, 40), np.int32)
+    tokens[0, :20], tokens[0, 20:33] = texts[0, :20], texts[1, :13]
+    segs[0, :20], segs[0, 20:33] = 1, 2
+    unit, pooled, _ = _embed_texts(params, CFG, tokens, segs,
+                                   jnp.zeros((), jnp.int32), 2)
     w, rc = ref_weights(params, CFG), ref_config(CFG)
     for b in range(2):
-        _, want = ref.hidden_states(w, rc, tokens[b], lens[b])
+        _, want = ref.hidden_states(w, rc, texts[b], lens[b])
         assert rel_gap(pooled[b], want) < TOL
         np.testing.assert_allclose(np.linalg.norm(unit[b]), 1.0, rtol=1e-5)
-    assert not np.any(np.asarray(pooled[2]))  # a padding row pools to 0
+    assert not np.any(np.asarray(pooled[2:]))  # a padding row pools to 0
 
 
 def test_expert_shares_add_up_to_the_whole_layer():
@@ -254,9 +261,10 @@ def test_embed_spans_count_texts_and_tokens(params, tmp_path):
         srv.step()
     rec = spans.recorded()
     embeds = [s for s in rec if s.name == "serve.embed"]
-    # each length bucket's 5 texts pad to the row bucket of 8
-    assert sorted((s.counts["texts"], s.counts["computed"])
-                  for s in embeds) == [(5, 8 * 16), (5, 8 * 32)]
+    # the 10 texts share rows of 32 tokens, 32 // 16 = 2 a row: 6 rows
+    # (first fit decreasing), padded to the row bucket of 8, in one call
+    assert [(s.counts["texts"], s.counts["rows"], s.counts["computed"])
+            for s in embeds] == [(10, 6, 8 * 32)]
     assert sum(s.counts["tokens"] for s in embeds) == int(lens.sum())
     steps = {i for i, s in enumerate(rec) if s.name == "serve.step"}
     assert all(s.parent in steps for s in embeds)
