@@ -4,7 +4,7 @@
 PY := PYTHONPATH=src python
 
 .PHONY: test test-fast test-priv test-comm test-async test-serve \
-	test-byz test-hier test-cov bench bench-round bench-serve bench-smoke
+	test-byz test-hier test-cov
 
 test:
 	$(PY) -m pytest -x -q
@@ -48,24 +48,3 @@ test-hier:
 test-cov:
 	$(PY) -m pytest -x -q --cov=repro --cov-report=term \
 		--cov-report=xml:coverage.xml
-
-bench-round:
-	$(PY) -m benchmarks.bench_round
-
-bench-serve:
-	$(PY) -m benchmarks.bench_serve
-
-# reduced-config benchmark pass for the CI smoke job: exercises every
-# BENCH_*.json writer (round engine, aggregator sweep, attention
-# fwd+bwd, DP delta pipeline, compressed transport, fault tolerance,
-# Byzantine grid, hierarchy two-hop, serving engine) in a few minutes
-bench-smoke:
-	$(PY) -m benchmarks.bench_round --rounds 30 --agg-rounds 10 --reps 2 \
-		--privacy --priv-rounds 30 --compress --comm-rounds 30 \
-		--faults --async-rounds 30 --byzantine --byz-rounds 25 \
-		--hierarchy --hier-rounds 30
-	$(PY) -m benchmarks.bench_serve --requests 24 --train-rounds 5 \
-		--reps 2 --rates 25,50,100
-
-bench:
-	$(PY) -m benchmarks.run

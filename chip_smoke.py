@@ -159,7 +159,7 @@ def phase_pallas(gcfg, data, tr, ev):
         if not f32:
             names = kernel_names(
                 fed._block, fed.global_params, fed.opt_states, fed.ef_resid,
-                fed.server_state, jax.random.PRNGKey(0),
+                fed.fault_state, fed.server_state, jax.random.PRNGKey(0),
                 jnp.ones((1,), bool))
             print(f"  kernels in the round's program: {sorted(names)}")
             check({"_gpo_fwd_kernel", "_gpo_bwd_dq_kernel",

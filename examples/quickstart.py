@@ -38,21 +38,20 @@ def main() -> None:
     #    deterministic per-seed schedule (hist.round_survivors records
     #    the realized participation); pair it with
     #    agg=AggConfig(name="fedbuff") for staleness-aware buffered
-    #    aggregation, and see `bench_round.py --faults` /
-    #    `dryrun.py --gpo-fed --faults` for the robustness numbers.
+    #    aggregation, and see `dryrun.py --gpo-fed --faults` for the
+    #    compiled fault round.
     #    To simulate Byzantine clients (DESIGN.md §13) add
     #      adversary=AdversaryConfig(kind="sign_flip", num_attackers=3)
     #    and pick a defense with agg=AggConfig(name="krum",
     #    num_malicious=3) (or geomedian/median, and/or norm_bound=1.0);
     #    from the CLI the same knobs are `train --trainer gpo
-    #    --attack sign_flip --attackers 3 --agg krum` — the attack ×
-    #    defense grid lives in `bench_round.py --byzantine`.
+    #    --attack sign_flip --attackers 3 --agg krum`.
     #    For client→edge→server aggregation (DESIGN.md §14) add
     #      hierarchy=HierarchyConfig(num_edges=4)
     #    — each edge pre-reduces its own client block before the
     #    cross-edge hop (the robust family's big all-gather shrinks
-    #    from O(C·P) to O(E·P); `dryrun.py --gpo-fed --edges 4` and
-    #    `bench_round.py --hierarchy` show the compiled byte counts).
+    #    from O(C·P) to O(E·P); `dryrun.py --gpo-fed --edges 4` shows
+    #    the compiled byte counts).
     gpo_cfg = GPOConfig(d_embed=data.phi.shape[-1])
     fed_cfg = FedConfig(num_clients=len(train_groups), rounds=150,
                         local_epochs=6, lr=3e-4, eval_every=25)

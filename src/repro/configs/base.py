@@ -191,7 +191,6 @@ class GPOConfig:
     num_layers: int = 4
     num_heads: int = 4
     d_ff: int = 256
-    dropout: float = 0.0
     norm_eps: float = 1e-6
     # Gaussian likelihood: if learn_sigma the head emits (mu, log_sigma),
     # else sigma=1 and Eq. 1's NLL reduces to MSE (GPO's practice).
@@ -203,11 +202,6 @@ class GPOConfig:
     # jax.grad runs the banded forward/backward grids instead of the
     # dense masked-softmax einsum. False = jnp everywhere.
     use_pallas_attention: bool = False
-    # unroll factor for the depth scan in gpo_apply. The while-loop (and
-    # its transpose in the backward pass) is pure overhead at the paper's
-    # small num_layers; num_layers (full unroll) removes it at the cost
-    # of a slightly larger executable. Same ops either way.
-    layer_unroll: int = 1
 
     @property
     def head_dim(self) -> int:
@@ -595,7 +589,7 @@ class AggConfig:
     # arrivals for EVERY strategy through this knob; 0.0 recovers the
     # classic synchronous baseline that lands stale deltas at full
     # weight — the failure mode fedbuff's discounted buffering exists
-    # to fix (the BENCH_async.json fedavg cells pin it to 0.0).
+    # to fix.
     staleness_power: float = 0.5
     # krum / multi_krum (Blanchard et al. 2017): the number of Byzantine
     # clients the selection must tolerate. Each client is scored by the
@@ -677,18 +671,6 @@ class FedConfig:
     num_context: int = 16  # m context points
     num_target: int = 16  # n - m target points
     batch_groups: int = 0  # 0 => all clients participate each round (paper)
-    # re-initialize client Adam moments each round (the paper leaves this
-    # unspecified; stale moments vs freshly-aggregated params can slow FL)
-    reset_opt_each_round: bool = False
-    # round driver: "scan" fuses blocks of rounds into one jitted
-    # lax.scan with on-device metric accumulation (DESIGN.md §3); "loop"
-    # is the per-round Python dispatch (one jit call + host sync per
-    # round), kept for A/B benchmarking and as the paper-faithful
-    # reference execution order.
-    engine: str = "scan"
-    # unroll factor for the fused scan driver (lax.scan unroll): trades
-    # compile time for less per-round loop machinery. 1 = no unroll.
-    scan_unroll: int = 1
     # aggregate with the Pallas reduction kernels on the flattened
     # (C, P) client-delta matrix instead of the per-leaf jnp reductions
     # (same math either way; see DESIGN.md §4, §7). Applies to both the

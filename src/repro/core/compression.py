@@ -35,8 +35,7 @@ the int8 payload + f32 per-client scales instead of f32 vectors — P + 4
 bytes per client instead of 4P, ~4× fewer bytes on the round's dominant
 collective. The linear family dequantizes shard-locally and keeps its
 single f32 psum (the psum models the server's reduction, not the
-client upload; the byte accounting lives in DESIGN.md §10 and
-``bench_round.py --compress`` → BENCH_comm.json).
+client upload; the byte accounting lives in DESIGN.md §10).
 """
 from __future__ import annotations
 

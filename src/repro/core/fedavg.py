@@ -1,14 +1,14 @@
 """FedAvg aggregation (paper Eq. 2-3) in three equivalent forms.
 
 1. ``fedavg_stacked`` — single-process simulation: client trees stacked on
-   a leading C axis, weighted sum along it. The paper-faithful CPU path.
+   a leading C axis, weighted sum along it (the one-device engine).
 2. ``fedavg_allreduce`` — the TPU-native form used inside ``shard_map``:
    each client shard scales its params by p_g and one weighted
    ``lax.psum`` over the client mesh axis *is* the aggregation server
    (DESIGN.md §3). Hierarchical (multi-pod) FedAvg is the same psum over
    ('pod', 'data').
 3. ``fedavg_flat`` — flattened-vector form matching the ``fedavg_reduce``
-   Pallas kernel contract (used by kernel tests and benchmarks).
+   Pallas kernel contract.
 
 These are the Eq. 2-3 *primitives*; the pluggable server-aggregation
 subsystem that generalizes them (delta contract, FedAvgM/FedAdam/

@@ -20,25 +20,28 @@ Round structure (faithful to the paper):
 
 Two execution engines expose the same round semantics:
 
-* ``FederatedGPO`` — clients vmapped on one device. This is the
-  paper-faithful simulation used for the CPU experiments (benchmarks
-  reproduce Figs. 2-5 with it).
+* ``FederatedGPO`` — clients vmapped on one device; the engine the
+  examples, the launchers and the chip benchmark's training cell run.
 * ``make_sharded_round`` — clients laid out on the mesh `data` axis via
   ``shard_map``; local epochs run without any cross-client collective and
   the round ends in ONE weighted psum (+ the hierarchical `pod` axis on
-  multi-pod meshes). This is the TPU-production engine the dry-run lowers.
+  multi-pod meshes). This is the multi-chip engine the dry-run lowers.
 
-``FederatedGPO`` itself has two round *drivers* (DESIGN.md §3):
+``FederatedGPO`` writes its round once (DESIGN.md §3): one participant
+head (cohort draw, broadcast, vmapped local epochs) and one of two tails,
+chosen statically — the pipeline's fused reduce, or with
+``AvailabilityConfig`` enabled the fault-aware release, blend and masked
+reduce. Two drivers run that round:
 
-* ``engine="scan"`` (default) — the fused multi-round driver: the whole
-  requested block of rounds is ONE jitted ``lax.scan`` (or blocks of
-  ``log_every`` rounds when live logging is requested). Per-round losses
-  and the eval-cadence alignment scores accumulate on device and transfer
-  to host once per block; the per-client optimizer buffers are donated
-  into the call. Zero per-round Python dispatch or device→host sync.
-* ``engine="loop"`` — one jitted call per round with a host sync on the
-  loss (the original dispatch pattern), kept for A/B benchmarking
-  (``benchmarks/bench_round.py``) and equivalence tests.
+* ``run(engine="scan")`` (default) — the requested rounds are ONE jitted
+  ``lax.scan`` (or blocks of ``log_every`` rounds when live logging is
+  requested). Per-round losses and the eval-cadence alignment scores
+  accumulate on device and transfer to host once per block; the
+  per-client optimizer buffers are donated into the call. Zero per-round
+  Python dispatch or device→host sync.
+* ``run(engine="loop")`` — one jitted round per call with a host sync on
+  the loss. The scan driver runs a block's tail shorter than a chunk this
+  way, and the equivalence tests hold the scan against it.
 
 Both drivers derive per-round RNG keys identically, so they produce the
 same ``History`` up to float reassociation. The round-key chain is the
@@ -59,17 +62,12 @@ import numpy as np
 from repro.configs.base import FedConfig, GPOConfig
 from repro.core import adversary as byz
 from repro.core import availability as av
-from repro.core import compression as cx, fairness, privacy as dp
+from repro.core import fairness, privacy as dp
 from repro.core.aggregation import ServerAggregator, make_aggregator
 from repro.core.pipeline import make_pipeline
-from repro.core.fedavg import (
-    broadcast_to_clients,
-    fedavg_allreduce,
-    normalize_weights,
-)
+from repro.core.fedavg import broadcast_to_clients, normalize_weights
 from repro.core.gpo import gpo_loss, init_gpo_params, predict_preferences
 from repro.data.surveys import SurveyData, sample_icl_batch
-from repro.kernels import fedavg_reduce
 from repro.optim import adam
 from repro.utils.pytree import (
     tree_count_params,
@@ -166,8 +164,15 @@ def _make_eval_group(gpo_cfg: GPOConfig, fed_cfg: FedConfig, data: SurveyData):
 
 
 # ---------------------------------------------------------------------------
-# Engine 1: vmapped clients (paper-faithful CPU simulation)
+# Engine 1: vmapped clients on one device
 # ---------------------------------------------------------------------------
+def _deleted(tree) -> bool:
+    """Whether any buffer of ``tree`` was consumed (donated); False for
+    None."""
+    return any(getattr(x, "is_deleted", lambda: False)()
+               for x in jax.tree.leaves(tree))
+
+
 @dataclass
 class History:
     round_loss: list = field(default_factory=list)  # mean client loss / round
@@ -239,10 +244,8 @@ class FederatedGPO:
         # fault injection (DESIGN.md §11): availability/failure state —
         # crash-rejoin traces plus the straggler in-flight buffer — rides
         # next to the server state; None keeps the fault-free trace
-        # byte-identical (the disabled default compiles the exact
-        # pre-feature round functions below).
-        self._faults = fed_cfg.avail.enabled
-        if self._faults:
+        # byte-identical (a None leaf adds no inputs to the round).
+        if fed_cfg.avail.enabled:
             self.fault_state = av.init_fault_state(
                 len(train_groups), tree_count_params(self.global_params))
         else:
@@ -275,16 +278,18 @@ class FederatedGPO:
         self._rounds_elapsed = 0
 
         agg = self.agg
-        priv = fed_cfg.privacy
         ef = comp.enabled and comp.error_feedback
         # round-stage pipeline (DESIGN.md §13): the [local_train, attack,
         # privacy, codec, aggregate] sequence assembles ONCE here; both
-        # stacked round bodies below delegate the stage dispatch to it
-        # (the attack-off pipeline traces the exact pre-§13 computation).
+        # round tails below delegate the stage dispatch to it (the
+        # attack-off pipeline traces the exact pre-§13 computation).
         pipe = make_pipeline(fed_cfg, agg=agg, num_clients=num_clients)
-        adv_on = fed_cfg.adversary.enabled
+        avail = fed_cfg.avail
 
-        def round_step(global_params, opt_states, server_state, resid, key):
+        def train_cohort(global_params, opt_states, key):
+            """The round's head, shared by both tails: draw the cohort,
+            broadcast the global model and run every participant's local
+            epochs."""
             k_sub, k_train = jax.random.split(key)
             if m < num_clients:
                 idx = jax.random.choice(k_sub, num_clients, (m,),
@@ -295,10 +300,7 @@ class FederatedGPO:
             sizes = data.sizes[groups].astype(jnp.float32)
             w = sizes / jnp.sum(sizes)
             client_params = broadcast_to_clients(global_params, m)
-            if fed_cfg.reset_opt_each_round:
-                opt_sub = jax.vmap(self.opt.init)(client_params)
-            else:
-                opt_sub = jax.tree.map(lambda x: x[idx], opt_states)
+            opt_sub = jax.tree.map(lambda x: x[idx], opt_states)
             keys = jax.random.split(k_train, m)
             # the Byzantine key folds out of the ROUND key (like the §11
             # fault key, its own tag) — None when the adversary is off,
@@ -310,6 +312,15 @@ class FederatedGPO:
             with jax.named_scope("local_train"):
                 new_client_params, opt_sub, losses = jax.vmap(local_train)(
                     *train_args)
+            return (idx, w, keys, bk, client_params, new_client_params,
+                    opt_sub, losses)
+
+        def sync_round(state, key):
+            """Fault-free tail: every participant's delta goes through the
+            pipeline's fused [attack →] privacy → codec → aggregate."""
+            global_params, opt_states, resid, fault, server_state = state
+            (idx, w, keys, bk, client_params, new_client_params, opt_sub,
+             losses) = train_cohort(global_params, opt_states, key)
             opt_states = jax.tree.map(
                 lambda full, sub: full.at[idx].set(sub), opt_states,
                 opt_sub)
@@ -325,72 +336,18 @@ class FederatedGPO:
                 resid=resid[idx] if ef else None, byz_key=bk)
             if ef:
                 resid = resid.at[idx].set(new_r)
-            return new_global, opt_states, server_state, resid, losses
+            return ((new_global, opt_states, resid, fault, server_state),
+                    losses, None, None)
 
-        def eval_fn(global_params, key):
-            keys = jax.random.split(key, len(eval_groups))
-            with jax.named_scope("eval"):
-                return jax.vmap(eval_group, in_axes=(None, 0, 0))(
-                    global_params, keys, self.eval_groups)
-
-        num_eval = len(eval_groups)
-
-        # Fused multi-round driver: a whole block of rounds is one jitted
-        # lax.scan. ``eval_mask`` (bool per round, known on the host) picks
-        # the rounds that also run the Eq. 4 evaluation; skipped rounds
-        # emit zeros that the host discards, so metric accumulation stays
-        # on device and the block performs exactly one host transfer.
-        # Only the per-client optimizer buffers and the EF compression
-        # residual are donated: callers (and the seed tests)
-        # legitimately hold references to the previous global model
-        # across ``run`` calls. The server-aggregator state (momentum /
-        # moments / adaptive scores) and the residual ride in the scan
-        # carry so stateful strategies and compressed transport fuse
-        # exactly like stateless FedAvg.
-        @functools.partial(jax.jit, donate_argnums=(1, 2))
-        def block_fn(global_params, opt_states, resid, server_state, key,
-                     eval_mask):
-            def body(carry, do_eval):
-                g, opt_s, r, srv, k = carry
-                k, k_round, k_eval = jax.random.split(k, 3)
-                g, opt_s, srv, r, losses = round_step(g, opt_s, srv, r,
-                                                      k_round)
-                scores = jax.lax.cond(
-                    do_eval,
-                    lambda gp, ke: eval_fn(gp, ke).astype(jnp.float32),
-                    lambda gp, ke: jnp.zeros((num_eval,), jnp.float32),
-                    g, k_eval)
-                return (g, opt_s, r, srv, k), (jnp.mean(losses), scores)
-
-            ((global_params, opt_states, resid, server_state, key),
-             (losses, scores)) = jax.lax.scan(
-                body, (global_params, opt_states, resid, server_state, key),
-                eval_mask, unroll=fed_cfg.scan_unroll)
-            return (global_params, opt_states, resid, server_state, key,
-                    losses, scores)
-
-        # ------------------------------------------------------------------
-        # Fault-aware round (DESIGN.md §11). A STATIC Python branch: with
-        # AvailabilityConfig disabled (the default) the round/block
-        # functions above compile exactly as before — the bit-equal pin
-        # in tests/test_availability.py rides on this. The fault round
-        # trades the fused reduce kernels for a per-client release
-        # (payloads must be individually maskable/bufferable) and keeps
-        # every failure decision inside the trace as masks: no Python
-        # branching on schedule values.
-        avail = fed_cfg.avail
-
-        def fault_round_step(global_params, opt_states, server_state,
-                             resid, fault, key):
-            k_sub, k_train = jax.random.split(key)
-            if m < num_clients:
-                idx = jax.random.choice(k_sub, num_clients, (m,),
-                                        replace=False)
-            else:
-                idx = jnp.arange(num_clients)
-            groups = self.train_groups[idx]
-            sizes = data.sizes[groups].astype(jnp.float32)
-            w = sizes / jnp.sum(sizes)
+        # Fault-aware tail (DESIGN.md §11). The fault round trades the
+        # fused reduce kernels for a per-client release (payloads must be
+        # individually maskable/bufferable) and keeps every failure
+        # decision inside the trace as masks: no Python branching on
+        # schedule values.
+        def fault_round(state, key):
+            global_params, opt_states, resid, fault, server_state = state
+            (idx, w, keys, bk, client_params, new_client_params, opt_sub,
+             losses) = train_cohort(global_params, opt_states, key)
             w_eff = agg.weigh(server_state, w, idx)
             # the failure schedule: a pure function of (round key, client
             # index, carried fault state) — replicated-computable, so the
@@ -407,19 +364,6 @@ class FederatedGPO:
                 fresh=sched.fresh & sampled,
                 crashed=sched.crashed & sampled,
                 straggle=sched.straggle & sampled)
-            client_params = broadcast_to_clients(global_params, m)
-            if fed_cfg.reset_opt_each_round:
-                opt_sub = jax.vmap(self.opt.init)(client_params)
-            else:
-                opt_sub = jax.tree.map(lambda x: x[idx], opt_states)
-            keys = jax.random.split(k_train, m)
-            bk = pipe.fold_key(key)
-            train_args = (client_params, opt_sub, keys, groups)
-            if pipe.flip_data:
-                train_args += (pipe.attacked_flags(bk, idx),)
-            with jax.named_scope("local_train"):
-                new_client_params, opt_sub, losses = jax.vmap(local_train)(
-                    *train_args)
             # opt states advance only where the round's local work
             # survived: offline clients never trained, crashed clients
             # lost theirs with the crash
@@ -491,42 +435,76 @@ class FederatedGPO:
             server_state = av.tree_where(any_surv, new_state, server_state)
             fault = av.advance_fault_state(fault, sched, rel_full, w_full,
                                            avail.rejoin_rounds)
-            # mean loss over clients whose local round survived
-            n_train = jnp.sum(keep.astype(jnp.float32))
-            loss_mean = (jnp.sum(jnp.where(keep, losses, 0.0))
-                         / jnp.maximum(n_train, 1.0))
-            return (new_global, opt_states, server_state, resid, fault,
-                    loss_mean, n_released)
+            return ((new_global, opt_states, resid, fault, server_state),
+                    losses, keep, n_released)
 
+        # a STATIC Python branch: with AvailabilityConfig disabled (the
+        # default) the round traces exactly the fault-free computation —
+        # the bit-equal pin in tests/test_availability.py rides on this
+        round_step = fault_round if avail.enabled else sync_round
+
+        def mean_loss(losses, keep):
+            """The round's mean client loss; under faults, over the
+            clients whose local round survived (``keep``)."""
+            if keep is None:
+                return jnp.mean(losses)
+            n_train = jnp.sum(keep.astype(jnp.float32))
+            return (jnp.sum(jnp.where(keep, losses, 0.0))
+                    / jnp.maximum(n_train, 1.0))
+
+        def eval_fn(global_params, key):
+            keys = jax.random.split(key, len(eval_groups))
+            with jax.named_scope("eval"):
+                return jax.vmap(eval_group, in_axes=(None, 0, 0))(
+                    global_params, keys, self.eval_groups)
+
+        num_eval = len(eval_groups)
+
+        # The carried round state is (global_params, opt_states, resid,
+        # fault, server_state); ``fault`` is None with faults off and
+        # ``resid`` without error feedback, and a None leaf adds no
+        # inputs to the trace. A whole block of rounds is one jitted
+        # lax.scan. ``eval_mask`` (bool per round, known on the host)
+        # picks the rounds that also run the Eq. 4 evaluation; skipped
+        # rounds emit zeros that the host discards, so metric
+        # accumulation stays on device and the block performs exactly one
+        # host transfer. Only the per-client optimizer buffers, the EF
+        # compression residual and the fault state are donated: callers
+        # (and the seed tests) legitimately hold references to the
+        # previous global model across ``run`` calls. The server-
+        # aggregator state rides in the scan carry so stateful strategies
+        # fuse exactly like stateless FedAvg.
         @functools.partial(jax.jit, donate_argnums=(1, 2, 3))
-        def fault_block_fn(global_params, opt_states, resid, fault,
-                           server_state, key, eval_mask):
+        def block_fn(global_params, opt_states, resid, fault, server_state,
+                     key, eval_mask):
             def body(carry, do_eval):
-                g, opt_s, r, f, srv, k = carry
+                state, k = carry
                 k, k_round, k_eval = jax.random.split(k, 3)
-                (g, opt_s, srv, r, f, loss,
-                 n_rel) = fault_round_step(g, opt_s, srv, r, f, k_round)
+                state, losses, keep, n_rel = round_step(state, k_round)
                 scores = jax.lax.cond(
                     do_eval,
                     lambda gp, ke: eval_fn(gp, ke).astype(jnp.float32),
                     lambda gp, ke: jnp.zeros((num_eval,), jnp.float32),
-                    g, k_eval)
-                return (g, opt_s, r, f, srv, k), (loss, scores, n_rel)
+                    state[0], k_eval)
+                return (state, k), (mean_loss(losses, keep), scores, n_rel)
 
-            ((global_params, opt_states, resid, fault, server_state, key),
-             (losses, scores, n_rel)) = jax.lax.scan(
-                body,
-                (global_params, opt_states, resid, fault, server_state,
-                 key), eval_mask, unroll=fed_cfg.scan_unroll)
-            return (global_params, opt_states, resid, fault, server_state,
-                    key, losses, scores, n_rel)
+            (state, key), (losses, scores, n_rel) = jax.lax.scan(
+                body, ((global_params, opt_states, resid, fault,
+                        server_state), key), eval_mask)
+            return (*state, key, losses, scores, n_rel)
 
-        if self._faults:
-            self._round = jax.jit(fault_round_step)
-            self._block = fault_block_fn
-        else:
-            self._round = jax.jit(round_step)
-            self._block = block_fn
+        # one round per call: the tail of a block shorter than a chunk,
+        # the loop driver, and the reference for the equivalence tests
+        @jax.jit
+        def round_fn(global_params, opt_states, resid, fault, server_state,
+                     key):
+            state, losses, keep, n_rel = round_step(
+                (global_params, opt_states, resid, fault, server_state),
+                key)
+            return (*state, mean_loss(losses, keep), n_rel)
+
+        self._block = block_fn
+        self._round = round_fn
         self._eval = jax.jit(eval_fn)
 
     def _eval_mask(self, rounds: int) -> np.ndarray:
@@ -562,15 +540,13 @@ class FederatedGPO:
                   f"FI={hist.eval_fi[-1]:.4f}")
 
     def run(self, rounds: int | None = None, log_every: int = 0,
-            engine: str | None = None) -> History:
+            engine: str = "scan") -> History:
         """Run ``rounds`` FedAvg rounds and return the metric ``History``.
 
-        ``engine`` overrides ``FedConfig.engine``: "scan" executes the
-        block as one fused jitted scan (default), "loop" dispatches one
-        jitted round at a time.
+        ``engine="scan"`` executes the rounds as fused jitted scans;
+        ``"loop"`` dispatches one jitted round at a time.
         """
         rounds = rounds or self.fed_cfg.rounds
-        engine = engine or self.fed_cfg.engine
         if rounds <= 0:
             return History()
         if engine == "scan":
@@ -611,20 +587,12 @@ class FederatedGPO:
         """One fused block of ``len(mask)`` rounds, appended to ``hist``."""
         with span("fed.dispatch"):
             try:
-                if self._faults:
-                    (self.global_params, self.opt_states, self.ef_resid,
-                     self.fault_state, self.server_state, self.round_key,
-                     losses, scores, n_rel) = self._block(
-                        self.global_params, self.opt_states, self.ef_resid,
-                        self.fault_state, self.server_state, self.round_key,
-                        jnp.asarray(mask))
-                else:
-                    (self.global_params, self.opt_states, self.ef_resid,
-                     self.server_state, self.round_key, losses,
-                     scores) = self._block(
-                        self.global_params, self.opt_states, self.ef_resid,
-                        self.server_state, self.round_key, jnp.asarray(mask))
-                    n_rel = None
+                (self.global_params, self.opt_states, self.ef_resid,
+                 self.fault_state, self.server_state, self.round_key,
+                 losses, scores, n_rel) = self._block(
+                    self.global_params, self.opt_states, self.ef_resid,
+                    self.fault_state, self.server_state, self.round_key,
+                    jnp.asarray(mask))
             except BaseException:
                 self._recover_donated_opt_states()
                 raise
@@ -650,19 +618,13 @@ class FederatedGPO:
         with span("fed.round"):
             self.round_key, k_round, k_eval = jax.random.split(
                 self.round_key, 3)
-            if self._faults:
-                (self.global_params, self.opt_states, self.server_state,
-                 self.ef_resid, self.fault_state, loss, n_rel) = self._round(
-                    self.global_params, self.opt_states, self.server_state,
-                    self.ef_resid, self.fault_state, k_round)
-                hist.round_loss.append(float(loss))
+            (self.global_params, self.opt_states, self.ef_resid,
+             self.fault_state, self.server_state, loss, n_rel) = self._round(
+                self.global_params, self.opt_states, self.ef_resid,
+                self.fault_state, self.server_state, k_round)
+            hist.round_loss.append(float(loss))
+            if n_rel is not None:
                 hist.round_survivors.append(int(n_rel))
-            else:
-                (self.global_params, self.opt_states, self.server_state,
-                 self.ef_resid, losses) = self._round(
-                    self.global_params, self.opt_states, self.server_state,
-                    self.ef_resid, k_round)
-                hist.round_loss.append(float(jnp.mean(losses)))
             self._note_privacy(hist, 1)
             if eval_mask[r]:
                 scores = np.asarray(self._eval(self.global_params, k_eval))
@@ -675,23 +637,17 @@ class FederatedGPO:
         Buffers that were never actually donated (e.g. interrupt during
         tracing, or a backend that ignores donation) are left alone.
         The donated EF residual recovers to zeros the same way (error
-        feedback restarts; the global model is untouched)."""
-        leaves = jax.tree.leaves(self.opt_states)
-        deleted = any(getattr(x, "is_deleted", lambda: False)()
-                      for x in leaves)
-        if deleted:
+        feedback restarts; the global model is untouched), and the fault
+        state to an empty one: the in-flight buffer is lost with the
+        interrupt, and deterministic replay resumes from the carried
+        round key."""
+        if _deleted(self.opt_states):
             per_client = broadcast_to_clients(self.global_params,
                                               len(self.train_groups))
             self.opt_states = jax.vmap(self.opt.init)(per_client)
-        if self.ef_resid is not None and getattr(
-                self.ef_resid, "is_deleted", lambda: False)():
+        if _deleted(self.ef_resid):
             self.ef_resid = jnp.zeros(self.ef_resid.shape, jnp.float32)
-        if self.fault_state is not None and any(
-                getattr(x, "is_deleted", lambda: False)()
-                for x in jax.tree.leaves(self.fault_state)):
-            # the in-flight buffer is lost with the interrupt; restart
-            # the schedule from an empty fault state (deterministic
-            # replay resumes from the carried round key)
+        if _deleted(self.fault_state):
             self.fault_state = av.init_fault_state(
                 len(self.train_groups),
                 tree_count_params(self.global_params))

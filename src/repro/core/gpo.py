@@ -144,8 +144,7 @@ def gpo_apply(params: dict, cfg: GPOConfig, ctx_x, ctx_y, tgt_x):
         x = x + _mm(jax.nn.gelu(_mm(h2, layer.w1)), layer.w2)
         return x, None
 
-    x, _ = jax.lax.scan(body, x, params["layers"],
-                        unroll=min(cfg.layer_unroll, cfg.num_layers))
+    x, _ = jax.lax.scan(body, x, params["layers"])
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     out = _mm(x[m:], params["head"])  # (t, 1 or 2)
     mu = out[:, 0]
@@ -216,8 +215,7 @@ def gpo_prefill(params: dict, cfg: GPOConfig, ctx_x, ctx_y,
         x = x + _mm(jax.nn.gelu(_mm(h2, layer.w1)), layer.w2)
         return x, (k, v)
 
-    _, (ks, vs) = jax.lax.scan(body, x, params["layers"],
-                               unroll=min(cfg.layer_unroll, cfg.num_layers))
+    _, (ks, vs) = jax.lax.scan(body, x, params["layers"])
     return GPOPrefix(k=ks, v=vs)
 
 
@@ -266,8 +264,7 @@ def gpo_decode(params: dict, cfg: GPOConfig, prefix: GPOPrefix, tgt_x,
         x = x + _mm(jax.nn.gelu(_mm(h2, layer.w1)), layer.w2)
         return x, None
 
-    x, _ = jax.lax.scan(body, x, (params["layers"], prefix.k, prefix.v),
-                        unroll=min(cfg.layer_unroll, cfg.num_layers))
+    x, _ = jax.lax.scan(body, x, (params["layers"], prefix.k, prefix.v))
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     out = _mm(x, params["head"])  # (T, 1 or 2)
     mu = out[:, 0]

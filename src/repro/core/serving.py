@@ -636,8 +636,8 @@ class PreferenceServer:
 
 
 # ---------------------------------------------------------------------------
-# synthetic load generation + latency summaries (shared by the serve CLI,
-# bench_serve.py, and the tests)
+# synthetic load generation + latency summaries (shared by the serve CLI
+# and the tests)
 # ---------------------------------------------------------------------------
 def make_request_trace(data, groups, *, num_requests: int,
                        hit_ratio: float = 0.0,

@@ -461,7 +461,7 @@ def _banded_ctx_blocks(num_ctx: int, bk: int, num_kb: int) -> int | None:
 
 def gpo_tile_counts(s: int, num_ctx: int, bq: int, bk: int) -> tuple[int, int]:
     """(banded_tiles, full_grid_tiles) per head for a given shape —
-    the grid-level work ratio reported by benchmarks/bench_round.py."""
+    the grid-level work ratio of the banded forward kernel."""
     num_qb, num_kb = s // bq, s // bk
     ctx_blocks = _banded_ctx_blocks(num_ctx, bk, num_kb)
     banded = num_qb * (ctx_blocks + 1 if ctx_blocks is not None else num_kb)
@@ -472,7 +472,7 @@ def gpo_tile_counts_bwd(s: int, num_ctx: int, bq: int,
                         bk: int) -> tuple[int, int]:
     """(banded_bwd_tiles, full_grid_bwd_tiles) per head: dq grid steps
     plus dk/dv grid steps — the backward-pass analogue of
-    ``gpo_tile_counts`` reported by benchmarks (BENCH_attn.json)."""
+    ``gpo_tile_counts``."""
     num_qb, num_kb = s // bq, s // bk
     ctx_blocks = _banded_ctx_blocks(num_ctx, bk, num_kb)
     full = 2 * num_qb * num_kb

@@ -15,7 +15,7 @@ The 2x16x16 multi-pod pass proves the 'pod' axis shards (hierarchical
 FedAvg / data parallelism over DCI); the roofline table is single-pod.
 
 ``main`` forces 512 host-platform devices before JAX starts its backend;
-importing this module (tests and benchmarks call ``lower_gpo_round``)
+importing this module (tests call ``lower_gpo_round``)
 changes nothing in the importing process.
 """
 

@@ -1,9 +1,9 @@
 """HLO cost engine: trip-count-aware FLOPs / bytes / collective analysis.
 
 ``compiled.cost_analysis()`` counts every while-loop body ONCE — for a
-layer-scanned transformer that under-counts compute by ~num_layers x
-(verified in EXPERIMENTS.md §Dry-run). This module parses the optimized
-HLO text and walks the call graph instead:
+layer-scanned transformer that under-counts compute by ~num_layers x.
+This module parses the optimized HLO text and walks the call graph
+instead:
 
   * dot ops: 2 * output_elems * contraction_size exact MXU FLOPs
     (contraction size from the operand symbol table);

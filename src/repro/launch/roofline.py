@@ -133,7 +133,7 @@ def analyze(cost: dict, hlo_text: str,
     """Roofline terms from the trip-count-aware HLO cost engine
     (launch/hlo_cost.py). ``cost`` (= compiled.cost_analysis()) is kept in
     the record as the XLA cross-check of the non-loop part — XLA counts
-    while bodies once, so it under-counts scanned models (EXPERIMENTS.md)."""
+    while bodies once, so it under-counts scanned models."""
     from repro.launch.hlo_cost import analyze_hlo
 
     totals = analyze_hlo(hlo_text)

@@ -9,8 +9,9 @@ Three trainer modes, all runnable on CPU with --smoke (reduced configs):
   fedlora   — frozen backbone, federated LoRA adapters (the large-arch
               recipe).
   gpo       — the paper's own experiment: federated GPO preference
-              predictor on synthetic survey data (see benchmarks/ for the
-              full figure reproduction).
+              predictor on synthetic survey data (see
+              examples/federated_vs_centralized.py for the paper's
+              federated-vs-centralized comparison).
 
 All federated trainers take ``--agg`` (plus the matching hyperparameter
 flags) to select the server-aggregation strategy from the registry in

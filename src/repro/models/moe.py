@@ -80,8 +80,7 @@ def _dispatch_block(xf, topk_idx, num_experts: int, k: int, cap: int):
     Returns (expert_in, target, token_of_pair, keep). vmapped over token
     blocks so that, with blocks laid out on the `data` axis, the scatter is
     shard-local — a replicated dispatch buffer would otherwise be
-    all-reduced across every data shard (measured 4 GB/occurrence f32 on
-    grok-1 train_4k; see EXPERIMENTS.md §Perf).
+    all-reduced across every data shard.
     """
     t, d = xf.shape
     flat_expert = topk_idx.reshape(-1)  # (T*k,)

@@ -7,8 +7,7 @@ Without a context (unit tests, CPU experiments) the calls are identity.
 
 Why this exists: without explicit constraints GSPMD is free to shard an
 attention contraction dimension, which materializes *partial* full-size
-score tensors and all-reduces them (measured: 721 GB/step on qwen2-0.5b
-train_4k, see EXPERIMENTS.md §Dry-run). Pinning activations to
+score tensors and all-reduces them. Pinning activations to
 batch->data, heads/ff/vocab->model (only when divisible) makes XLA move
 weights (small) instead of activations (huge) — the standard production
 layout.

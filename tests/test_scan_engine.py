@@ -105,7 +105,7 @@ def test_run_zero_rounds_returns_empty_history():
 
 def test_scan_engine_is_default_and_resumable():
     fed = _make_fed()
-    hist1 = fed.run(rounds=3)  # FedConfig.engine == "scan"
+    hist1 = fed.run(rounds=3)  # run(engine="scan") is the default
     assert len(hist1.round_loss) == 3
     assert hist1.eval_rounds == [0, 2]
     # a second block continues from the advanced state without error
